@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import binning, boosting, proposal, tree as tree_lib
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 _JSON_PATH = os.path.join(os.path.dirname(__file__), "..",
                           "BENCH_gbdt_step.json")
@@ -311,6 +312,7 @@ def main() -> None:
                     help="tiny CI invariant check (trace count, scatter-"
                          "update reduction); no timings, no JSON write")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         smoke()
         return

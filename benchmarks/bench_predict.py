@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import predict as predict_lib, tree as tree_lib
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_gbdt import synthetic_gbdt
 from repro.obs import PredictReport
 
@@ -186,6 +187,7 @@ def main() -> None:
                     help="tiny CI invariant check (trace count, "
                          "bit-identity); no timings, no JSON write")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         smoke()
         return

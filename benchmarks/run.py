@@ -17,6 +17,7 @@ import traceback
 from . import (bench_gbdt_step, bench_kernels, bench_predict,
                bench_proposal_time, bench_rank_error, bench_roofline,
                bench_table2)
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("rank_error", bench_rank_error),
@@ -30,6 +31,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     rows: list = []
     print("name,us_per_call,derived")

@@ -25,9 +25,11 @@ from jax.sharding import Mesh                                   # noqa: E402
 
 import repro                                                    # noqa: E402
 from repro.data import make_dataset                             # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache     # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     print(f"devices: {len(jax.devices())}")
     xtr, ytr, xte, yte, _ = make_dataset("higgs-like", 32_768, 8_192)
     mesh = Mesh(np.array(jax.devices()).reshape(-1), ("data",))

@@ -19,9 +19,11 @@ import repro
 from repro.core import rank_error                  # research experiment,
 from repro.core.boosting import round_trace_count  # trace diagnostic —
 from repro.data import make_dataset       # all outside the stable surface
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     print("=== 1. GBDT: random sampling (S) vs quantile sketch (Q) ===")
     xtr, ytr, xte, yte, _ = make_dataset("susy-like", 20_000, 5_000)
     results = {}

@@ -45,11 +45,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import binning, boosting, proposal, sketch, tree as tree_lib
-from .. import compat, obs
+from .. import obs
 from ..kernels import ops
 
 
@@ -223,6 +224,41 @@ def _worker_fit_reference(x_local, y_local, w_local, key, *,
             margin)
 
 
+def sharded_fit(cfg: boosting.GBDTConfig, mesh: Mesh, *, axis: str,
+                n_global: int, reference: bool = False):
+    """The jitted row-sharded training program of :func:`fit_distributed`.
+
+    Takes ``(x, y, valid, key)`` with rows sharded over ``axis`` (the
+    row count divisible by the worker count) and returns ``(forest,
+    candidates, base, margin)``, plus the stacked report when
+    ``cfg.telemetry`` is on and ``reference`` is off.
+    """
+    worker = _worker_fit_reference if reference else _worker_fit
+    telemetry = cfg.telemetry and not reference
+    fn = functools.partial(worker, cfg=cfg, axis=axis, n_global=n_global,
+                           spec=cfg.hist_spec().resolved())
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(P(axis, None), P(axis), P(axis), P()),
+        out_specs=(P(), P(), P(), P(axis)) + ((P(),) if telemetry else ()),
+        check_vma=False,
+    ))
+
+
+def _stage(a, sharding: NamedSharding, pad: int) -> jax.Array:
+    """``a`` as f32 with its first ``pad`` rows repeated at the end, laid
+    out by ``sharding``.  A ``jax.Array`` already laid out so is used as
+    it is; anything else is padded on the host and only each device's
+    shard is transferred, so the full array never lands on one device."""
+    if (not pad and isinstance(a, jax.Array) and a.dtype == jnp.float32
+            and a.sharding.is_equivalent_to(sharding, a.ndim)):
+        return a
+    a = np.asarray(a, np.float32)
+    if pad:
+        a = np.concatenate([a, a[:pad]], 0)
+    return jax.device_put(a, sharding)
+
+
 def fit_distributed(x, y, cfg: boosting.GBDTConfig, mesh: Mesh,
                     key: jax.Array | None = None,
                     axis: str = "data",
@@ -235,44 +271,32 @@ def fit_distributed(x, y, cfg: boosting.GBDTConfig, mesh: Mesh,
     repeats of the leading rows for static shard shapes, but a per-row
     validity weight zeroes the pad rows' grad/hess and label mass, so
     base score, histograms, and leaf values are exactly those of the
-    unpadded data.  ``reference=True`` runs the unrolled oracle loop
-    instead of the scanned trainer (tests only).
+    unpadded data.  ``x`` and ``y`` already row-sharded over ``axis`` (and
+    needing no pad) are used in place; anything else goes through host
+    memory.  ``reference=True`` runs the unrolled oracle loop instead of
+    the scanned trainer (tests only).
     """
     if key is None:
         key = jax.random.PRNGKey(0)
-    x = jnp.asarray(x, jnp.float32)
-    y = jnp.asarray(y, jnp.float32)
     n_true = x.shape[0]
     nw = mesh.shape[axis]
-    valid = jnp.ones((n_true,), jnp.float32)
-    if n_true % nw:
-        pad = nw - n_true % nw
-        # repeat leading rows so shard shapes stay static; their weight
-        # is zero, so they never reach a psum'd statistic
-        x = jnp.concatenate([x, x[:pad]], 0)
-        y = jnp.concatenate([y, y[:pad]], 0)
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), jnp.float32)], 0)
-
-    xs = jax.device_put(x, NamedSharding(mesh, P(axis, None)))
-    ys = jax.device_put(y, NamedSharding(mesh, P(axis)))
+    # repeat leading rows so shard shapes stay static; their weight is
+    # zero, so they never reach a psum'd statistic
+    pad = -n_true % nw
+    valid = np.concatenate([np.ones((n_true,), np.float32),
+                            np.zeros((pad,), np.float32)])
+    xs = _stage(x, NamedSharding(mesh, P(axis, None)), pad)
+    ys = _stage(y, NamedSharding(mesh, P(axis)), pad)
     ws = jax.device_put(valid, NamedSharding(mesh, P(axis)))
 
-    worker = _worker_fit_reference if reference else _worker_fit
-    telemetry = cfg.telemetry and not reference
-    fn = functools.partial(worker, cfg=cfg, axis=axis, n_global=n_true,
-                           spec=cfg.hist_spec().resolved())
-    out = jax.jit(compat.shard_map(
-        fn, mesh=mesh,
-        in_specs=(P(axis, None), P(axis), P(axis), P()),
-        out_specs=(P(), P(), P(), P(axis)) + ((P(),) if telemetry else ()),
-        check_vma=False,
-    ))(xs, ys, ws, key)
+    out = sharded_fit(cfg, mesh, axis=axis, n_global=n_true,
+                      reference=reference)(xs, ys, ws, key)
     forest, cands, base, _margin = out[:4]
 
     report = None
-    if telemetry:
+    if cfg.telemetry and not reference:
         report = out[4]
-        ag, ps = obs.collective_bytes_per_round(cfg, x.shape[1], nw)
+        ag, ps = obs.collective_bytes_per_round(cfg, xs.shape[1], nw)
         report = report._replace(all_gather_bytes=jnp.asarray(ag),
                                  psum_bytes=jnp.asarray(ps))
     return boosting.GBDTModel(cfg, forest, float(base), cands,

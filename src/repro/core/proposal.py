@@ -157,27 +157,17 @@ def exact_candidates(x: np.ndarray, k: int) -> np.ndarray:
 # Unified front end.
 # ---------------------------------------------------------------------------
 
-def _in_traced_context(*operands) -> bool:
-    """True when we are inside a jit/scan trace (any operand is a tracer,
-    or the global trace state is dirty)."""
-    if any(isinstance(a, jax.core.Tracer) for a in operands if a is not None):
-        return True
-    return not jax.core.trace_state_clean()
-
-
 def propose(strategy: Strategy, x, k: int, *, key: jax.Array | None = None,
             hess: jax.Array | None = None,
-            traced: bool | None = None) -> jnp.ndarray:
+            traced: bool = False) -> jnp.ndarray:
     """Unified proposal dispatch (distributed version in distributed.py).
 
-    One entry point for both host code and jit-traced code: with
-    ``traced=None`` (default) the jit context is auto-detected — any
-    tracer operand, or a dirty trace state, selects the traced path,
-    which restricts dispatch to the :data:`TRACEABLE` strategies (pure
-    jax ops, safe inside a ``lax.scan`` round step).  Host-only
-    strategies ('gk_quantile', 'exact') run numpy on concrete arrays and
-    raise ``ValueError`` if requested while tracing.  Pass
-    ``traced=True``/``False`` to force a path.
+    One entry point for both host code and jit-traced code.  The
+    :data:`TRACEABLE` strategies are pure jax ops and run either way.
+    The host-only strategies ('gk_quantile', 'exact') are x-only numpy
+    and raise ``ValueError`` when ``x`` is a ``jax.core.Tracer`` (the
+    trainers propose them once, outside the trace) or when
+    ``traced=True`` asks for the jit-safe path.
 
     Args:
       x: (n, f) feature matrix.
@@ -189,8 +179,6 @@ def propose(strategy: Strategy, x, k: int, *, key: jax.Array | None = None,
     Returns:
       (f, k) sorted float32 candidates.
     """
-    if traced is None:
-        traced = _in_traced_context(x, key, hess)
     if strategy == "random":
         if key is None:
             raise ValueError("random proposal needs a PRNG key")
@@ -203,7 +191,7 @@ def propose(strategy: Strategy, x, k: int, *, key: jax.Array | None = None,
         return uniform_range_candidates(jnp.asarray(x), k)
     if strategy not in ("gk_quantile", "exact"):
         raise ValueError(f"unknown strategy {strategy!r}")
-    if traced:
+    if traced or isinstance(x, jax.core.Tracer):
         raise ValueError(
             f"strategy {strategy!r} is host-only (numpy) and cannot run "
             f"under jit; propose outside the trace (TRACEABLE={TRACEABLE})")
@@ -215,7 +203,7 @@ def propose(strategy: Strategy, x, k: int, *, key: jax.Array | None = None,
 def propose_traced(strategy: Strategy, x: jax.Array, k: int,
                    key: jax.Array, hess: jax.Array) -> jax.Array:
     """Deprecated: use ``propose(strategy, x, k, key=key, hess=hess)`` —
-    the unified dispatcher auto-detects jit context."""
+    the unified dispatcher serves traced and host code alike."""
     warnings.warn(
         "propose_traced is deprecated; use propose(strategy, x, k, "
         "key=key, hess=hess)", DeprecationWarning, stacklevel=2)

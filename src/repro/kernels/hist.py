@@ -8,7 +8,7 @@ TPU-native formulation is **histogram-as-matmul**:
   for a tile of rows, build the one-hot matrix  O[r, (node,bin)]  and
   contract it with the (rows, 2) grad/hess panel on the MXU:
 
-      hist[f, node*nbins+bin, :] += O.T @ [g h]
+      hist[f, :, node*nbins+bin] += [g h].T @ O
 
 The one-hot never leaves VMEM; the contraction dimension (rows tile) is a
 multiple of 128 so the MXU is fully utilised.
@@ -36,28 +36,40 @@ DEFAULT_ROW_TILE = 512
 
 def _hist_levels_kernel(bins_ref, node_ref, gh_ref, out_ref, *,
                         nbins: int, node_chunk: int, n_chunks: int):
-    t = pl.program_id(2)
+    fi, c, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(t == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bins = bins_ref[:, 0]                       # (rt,) int32
-    node = node_ref[:, 0]                       # (rt,) int32 (-1 = padding)
-    gh = gh_ref[...].astype(jnp.float32)        # (rt, 2)
+    # the bins and node blocks span every feature / level column (the
+    # TPU tiling wants a block's minor dim to be the array's whole
+    # width); pick this grid step's column with a one-hot lane select,
+    # which is value-exact (one hot lane, the rest add integer zeros)
+    def column(block, j):
+        lanes = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+        return jnp.sum(jnp.where(lanes == j, block, 0), axis=1,
+                       keepdims=True)                 # (rt, 1) int32
 
-    # middle grid axis c enumerates (level, chunk): level = c // n_chunks
-    # is encoded in the node BlockSpec; only the chunk offset matters here.
-    base = (pl.program_id(1) % n_chunks) * node_chunk
+    bins = column(bins_ref[...], fi)
+    # middle grid axis c enumerates (level, chunk)
+    node = column(node_ref[...], c // n_chunks)       # -1 = padding
+    gh = gh_ref[...].astype(jnp.float32)              # (rt, 2)
+
+    base = (c % n_chunks) * node_chunk
     local = node - base
     valid = (local >= 0) & (local < node_chunk)
-    idx = jnp.where(valid, local * nbins + bins, 0)
+    idx = jnp.where(valid, local * nbins + bins, -1)
 
     width = node_chunk * nbins
     cols = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], width), 1)
-    onehot = ((idx[:, None] == cols) & valid[:, None]).astype(jnp.float32)
-
-    out_ref[0] += jnp.dot(onehot.T, gh, preferred_element_type=jnp.float32)
+    onehot = (idx == cols).astype(jnp.float32)        # (rt, width)
+    # {g, h} on sublanes, (node, bin) on lanes: gh.T @ onehot, with f32
+    # operands kept f32 on the MXU (the default would round gh to bf16)
+    out_ref[0, 0] += jax.lax.dot_general(
+        gh, onehot, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -103,19 +115,22 @@ def hist_levels_pallas(bins: jax.Array, node_per_level: jax.Array,
                           node_chunk=node_chunk, n_chunks=n_chunks),
         grid=(f, L * n_chunks, nt),
         in_specs=[
-            pl.BlockSpec((row_tile, 1), lambda fi, c, t: (t, fi)),
-            pl.BlockSpec((row_tile, 1), lambda fi, c, t: (t, c // n_chunks)),
+            pl.BlockSpec((row_tile, f), lambda fi, c, t: (t, 0)),
+            pl.BlockSpec((row_tile, L), lambda fi, c, t: (t, 0)),
             pl.BlockSpec((row_tile, 2), lambda fi, c, t: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, node_chunk * nbins, 2),
-                               lambda fi, c, t: (fi, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((f, L * nodes_padded * nbins, 2),
-                                       jnp.float32),
+        # one (2, node_chunk * nbins) panel per (feature, level, chunk):
+        # its last two dims are the array's whole, as the TPU tiling wants
+        out_specs=pl.BlockSpec((1, 1, 2, node_chunk * nbins),
+                               lambda fi, c, t: (fi, c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (f, L * n_chunks, 2, node_chunk * nbins), jnp.float32),
         interpret=interpret,
     )(bins, node_t, gh)
 
-    out = out.reshape(f, L, nodes_padded, nbins, 2)[:, :, :n_nodes]
-    return jnp.transpose(out, (1, 2, 0, 3, 4))
+    out = out.reshape(f, L, n_chunks, 2, node_chunk, nbins)
+    out = jnp.transpose(out, (1, 2, 4, 0, 5, 3))
+    return out.reshape(L, nodes_padded, f, nbins, 2)[:, :n_nodes]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -143,21 +158,3 @@ def hist_levels_left_pallas(bins: jax.Array, node_per_level: jax.Array,
     return hist_levels_pallas(bins, parent, gh, n_nodes=n_nodes,
                               nbins=nbins, row_tile=row_tile,
                               node_chunk=node_chunk, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "n_nodes", "nbins", "row_tile", "node_chunk", "interpret"))
-def hist_pallas(bins: jax.Array, node: jax.Array, gh: jax.Array, *,
-                n_nodes: int, nbins: int,
-                row_tile: int = DEFAULT_ROW_TILE,
-                node_chunk: int = 0,
-                interpret: bool = False) -> jax.Array:
-    """Per-(node, feature, bin) grad/hess sums — single-level view of
-    :func:`hist_levels_pallas`.
-
-    Returns:
-      (n_nodes, f, nbins, 2) float32 histogram.
-    """
-    return hist_levels_pallas(bins, node[None], gh, n_nodes=n_nodes,
-                              nbins=nbins, row_tile=row_tile,
-                              node_chunk=node_chunk, interpret=interpret)[0]

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Each op picks between the Pallas kernel (TPU, or interpret=True for CPU
-validation) and the pure-jnp oracle in ref.py.  Call sites in the library
-go through these wrappers only — never through the kernels directly — so
-backend selection is a single switch.
+validation), the XLA 'packed' path and the pure-jnp oracle in ref.py.
+Call sites in the library go through these wrappers only — never
+through the kernels directly — so backend selection is a single switch.
 
 The histogram hot path is fronted by a small kernel API: a
 :class:`HistSpec` (static shape/backend/dtype policy, hashable so it can
@@ -21,7 +21,7 @@ import warnings
 import jax
 
 from . import ref
-from .hist import hist_levels_left_pallas, hist_levels_pallas, hist_pallas
+from .hist import hist_levels_left_pallas, hist_levels_pallas
 from .split_gain import split_gain_pallas
 from .traverse import traverse_chunk_pallas
 from .flash_attention import flash_attention_pallas
@@ -38,16 +38,15 @@ def resolve(backend: str) -> str:
     """Resolve 'auto' to a concrete backend name.
 
     The scanned trainers call this once per fit, outside traced code, so
-    the choice is a static constant of the compiled program (and
-    ``jax.default_backend()`` is never consulted mid-trace).  On CPU
-    'auto' picks 'packed' — the complex64-scatter histogram, bit-exact
-    vs the 'ref' oracle but ~1.6x faster through XLA:CPU.
+    the choice is a static constant of the compiled program.  'auto'
+    picks 'packed' — the XLA path (complex64-scatter histogram, packed
+    record gathers), bit-exact vs the 'ref' oracle — on every platform,
+    TPU included: whether a Pallas kernel beats it on the chip is not
+    measured yet.  ``backend='pallas'`` still asks for the kernels.
     """
-    if backend == "auto":
-        return "pallas" if _on_tpu() else "packed"
     if backend not in _BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")
-    return backend
+    return "packed" if backend == "auto" else backend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,8 +208,7 @@ class TraverseSpec:
         the LAST bin (so they follow the binned routing) while raw NaN
         compares False and routes RIGHT.
       backend: 'auto' | 'pallas' | 'interpret' | 'ref' | 'packed'; same
-        resolution rule as histograms ('auto' -> pallas on TPU, packed
-        elsewhere).
+        resolution rule as histograms ('auto' -> packed).
     """
     tree_chunk: int = 25
     binned: bool = False

@@ -268,14 +268,25 @@ def _score(g, h, l2):
     return (g * g) / (h + l2)
 
 
+def _prefix_sum(a: jax.Array) -> jax.Array:
+    """Left-to-right prefix sum over the last axis, ``((a0 + a1) + a2)
+    ...``.  One fixed association on every backend, the same one the
+    Pallas split-gain kernel unrolls: ``jnp.cumsum`` regroups long axes
+    differently per backend, which moves gains by a few ulps."""
+    out = [a[..., :1]]
+    for s in range(1, a.shape[-1]):
+        out.append(out[-1] + a[..., s:s + 1])
+    return jnp.concatenate(out, axis=-1)
+
+
 @functools.partial(jax.jit, static_argnames=())
 def split_gain_ref(hist: jax.Array, *, l2: float = 1.0, gamma: float = 0.0,
                    min_child_weight: float = 1e-6):
     """Best gain / split-bin per (node, feature) — oracle for split_gain."""
     g = hist[..., 0]
     h = hist[..., 1]
-    gl = jnp.cumsum(g, axis=2)
-    hl = jnp.cumsum(h, axis=2)
+    gl = _prefix_sum(g)
+    hl = _prefix_sum(h)
     gt = gl[..., -1:]
     ht = hl[..., -1:]
     gr = gt - gl

@@ -21,29 +21,38 @@ from jax.experimental import pallas as pl
 
 def _split_gain_kernel(hist_ref, gain_ref, idx_ref, *,
                        l2: float, gamma: float, min_child_weight: float):
-    hist = hist_ref[0]                       # (f, nbins, 2) f32
-    g = hist[..., 0]
-    h = hist[..., 1]
-    gl = jnp.cumsum(g, axis=1)               # (f, nbins) left sums incl bin s
-    hl = jnp.cumsum(h, axis=1)
-    gt = gl[:, -1:]
-    ht = hl[:, -1:]
-    gr = gt - gl
-    hr = ht - hl
+    g = hist_ref[0, 0]                       # (nbins, f) f32
+    h = hist_ref[0, 1]
+    nbins = g.shape[0]
 
     def score(gg, hh):
         return (gg * gg) / (hh + l2)
 
-    gain = 0.5 * (score(gl, hl) + score(gr, hr) - score(gt, ht)) - gamma
-    ok = (hl >= min_child_weight) & (hr >= min_child_weight)
-    # splitting at the last bin puts everything left — never useful
-    nbins = gain.shape[1]
-    pos = jax.lax.broadcasted_iota(jnp.int32, gain.shape, 1)
-    ok &= pos < (nbins - 1)
-    gain = jnp.where(ok, gain, -jnp.inf)
+    # left sums incl. bin s, as (1, f) rows, added left to right like
+    # ref.split_gain_ref: Pallas TPU has no cumsum, and the static bin
+    # axis is short enough to unroll
+    gl, hl = [g[0:1]], [h[0:1]]
+    for s in range(1, nbins):
+        gl.append(gl[-1] + g[s:s + 1])
+        hl.append(hl[-1] + h[s:s + 1])
+    gt, ht = gl[-1], hl[-1]
 
-    gain_ref[0] = jnp.max(gain, axis=1)
-    idx_ref[0] = jnp.argmax(gain, axis=1).astype(jnp.int32)
+    best = jnp.full(gt.shape, -jnp.inf, jnp.float32)
+    best_s = jnp.zeros(gt.shape, jnp.int32)
+    # splitting at the last bin puts everything left — never useful
+    for s in range(nbins - 1):
+        gr = gt - gl[s]
+        hr = ht - hl[s]
+        gain = 0.5 * (score(gl[s], hl[s]) + score(gr, hr)
+                      - score(gt, ht)) - gamma
+        ok = (hl[s] >= min_child_weight) & (hr >= min_child_weight)
+        gain = jnp.where(ok, gain, -jnp.inf)
+        better = gain > best                 # first maximum wins (argmax)
+        best = jnp.where(better, gain, best)
+        best_s = jnp.where(better, s, best_s)
+
+    gain_ref[0] = best
+    idx_ref[0] = best_s
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -67,15 +76,17 @@ def split_gain_pallas(hist: jax.Array, *, l2: float = 1.0, gamma: float = 0.0,
     gains, idx = pl.pallas_call(
         kern,
         grid=(n_nodes,),
-        in_specs=[pl.BlockSpec((1, f, nbins, 2), lambda i: (i, 0, 0, 0))],
+        in_specs=[pl.BlockSpec((1, 2, nbins, f), lambda i: (i, 0, 0, 0))],
+        # a (1, f) row per node: the unit axis makes the block's last
+        # two dims the array's whole (1, f), as the TPU tiling requires
         out_specs=[
-            pl.BlockSpec((1, f), lambda i: (i, 0)),
-            pl.BlockSpec((1, f), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, f), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, f), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_nodes, f), jnp.float32),
-            jax.ShapeDtypeStruct((n_nodes, f), jnp.int32),
+            jax.ShapeDtypeStruct((n_nodes, 1, f), jnp.float32),
+            jax.ShapeDtypeStruct((n_nodes, 1, f), jnp.int32),
         ],
         interpret=interpret,
-    )(hist)
-    return gains, idx
+    )(jnp.transpose(hist, (0, 3, 2, 1)))    # {g,h} x bins x features
+    return gains[:, 0], idx[:, 0]

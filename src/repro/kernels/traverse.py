@@ -32,7 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-DEFAULT_ROW_TILE = 256
+# The (rows, chunk, width) masked-select temporaries must fit the 16 MB
+# of scoped VMEM: 256 rows overran it at depth 8 with 25 trees, 64 fit.
+DEFAULT_ROW_TILE = 64
 
 
 def _traverse_kernel(vals_ref, feat_ref, cmp_ref, leaf_ref, out_ref, *,

@@ -22,7 +22,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from ..configs import ARCH_NAMES, INPUT_SHAPES, get_config
 from ..models.sharding import logical_rules, rules_for_mesh
 from ..optim import AdamWConfig
@@ -46,7 +45,7 @@ def build_lowered(cfg, shape, mesh, opt_cfg=None, overrides=None):
     window = specs.decode_window(cfg, shape)
     bspecs = specs.input_specs(cfg, shape)
 
-    with compat.use_mesh(mesh), logical_rules(rules):
+    with jax.set_mesh(mesh), logical_rules(rules):
         if shape.kind == "train":
             pshapes, oshapes = steps.train_state_shapes(cfg, opt_cfg)
             pshard = param_shardings(pshapes, mesh, cfg)

@@ -36,6 +36,7 @@ from ..core import boosting, tree as tree_lib
 from ..core.predict import DEFAULT_TREE_CHUNK
 from ..obs import PredictReport
 from . import mesh as mesh_lib
+from .compile_cache import enable_compile_cache
 
 
 def synthetic_gbdt(*, n_trees: int, max_depth: int, n_features: int,
@@ -89,9 +90,7 @@ def serve(model: boosting.GBDTModel, *, microbatch: int = 4096,
     binning) so every measured request hits the executable cache.
     """
     cfg = model.config
-    f = model.forest  # noqa: F841  (keep the forest resident)
-    n_features = (model.bin_edges.shape[0] if model.bin_edges is not None
-                  else int(jnp.max(model.forest.feature)) + 1)
+    n_features = model.candidates.shape[1]      # (rounds, f, k)
     rng = np.random.default_rng(seed)
     batches = [rng.normal(size=(microbatch, n_features)).astype(np.float32)
                for _ in range(n_requests)]
@@ -151,6 +150,7 @@ def main(argv=None) -> PredictReport:
     p.add_argument("--json", default=None,
                    help="write the PredictReport JSON here")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     if args.ckpt:
         from ..checkpoint import load_gbdt

@@ -29,6 +29,17 @@ for strat in ("random", "weighted_quantile"):
     m = distributed.fit_distributed(X, y, cfg, mesh, key)
     out[strat] = boosting.accuracy(m, X, y)
 
+# inputs already row-sharded are used in place and give the same forest
+from jax.sharding import NamedSharding, PartitionSpec as P
+cfg = boosting.GBDTConfig(n_trees=4, max_depth=4, n_candidates=16)
+ma = distributed.fit_distributed(np.asarray(X), np.asarray(y), cfg, mesh, key)
+mb = distributed.fit_distributed(
+    jax.device_put(X, NamedSharding(mesh, P("data", None))),
+    jax.device_put(y, NamedSharding(mesh, P("data"))), cfg, mesh, key)
+out["presharded_same"] = all(
+    bool(np.array_equal(np.asarray(a), np.asarray(b)))
+    for a, b in zip(ma.forest, mb.forest))
+
 # single-host reference with identical config
 cfg = boosting.GBDTConfig(n_trees=4, max_depth=4, n_candidates=16)
 m1 = boosting.fit(X, y, cfg, key)
@@ -57,6 +68,11 @@ def test_runs_on_8_workers(dist_result):
 
 def test_distributed_random_learns(dist_result):
     assert dist_result["random"] > 0.85
+
+
+def test_presharded_inputs_match_host_inputs(dist_result):
+    """x, y already sharded over the mesh skip host staging, same trees."""
+    assert dist_result["presharded_same"], dist_result
 
 
 def test_distributed_random_matches_quantile(dist_result):
