@@ -152,21 +152,33 @@ class GBDTModel:
         All output modes route through ONE jitted ensemble-sum
         executable per (shapes, spec) — picking 'proba' after 'label'
         does not recompile or re-traverse differently.
+
+        Under ``jax.profiler`` the call is the host span
+        ``repro.predict``, with children ``repro.predict.input``
+        (conversion, transfer, binning), ``repro.predict.spec``, and
+        :func:`repro.core.predict.margin`'s ``repro.predict.dispatch``
+        and ``repro.predict.affine``.
         """
-        x = jnp.asarray(x)
-        if binned and not jnp.issubdtype(x.dtype, jnp.integer):
-            x = self.bin_features(x)
-        elif binned:
-            if self.bin_edges is None:
-                raise ValueError(
-                    "binned predict needs a fixed candidate grid "
-                    "(see GBDTModel.bin_features)")
-        else:
-            x = x.astype(jnp.float32)
-        spec = TraverseSpec(
-            tree_chunk=tree_chunk or predict_lib.DEFAULT_TREE_CHUNK,
-            binned=binned,
-            backend=backend or self.config.backend).resolved()
+        with jax.profiler.TraceAnnotation("repro.predict"):
+            return self._predict(x, output, binned, backend, tree_chunk)
+
+    def _predict(self, x, output, binned, backend, tree_chunk):
+        with jax.profiler.TraceAnnotation("repro.predict.input"):
+            x = jnp.asarray(x)
+            if binned and not jnp.issubdtype(x.dtype, jnp.integer):
+                x = self.bin_features(x)
+            elif binned:
+                if self.bin_edges is None:
+                    raise ValueError(
+                        "binned predict needs a fixed candidate grid "
+                        "(see GBDTModel.bin_features)")
+            else:
+                x = x.astype(jnp.float32)
+        with jax.profiler.TraceAnnotation("repro.predict.spec"):
+            spec = TraverseSpec(
+                tree_chunk=tree_chunk or predict_lib.DEFAULT_TREE_CHUNK,
+                binned=binned,
+                backend=backend or self.config.backend).resolved()
         m = predict_lib.margin(
             self.forest, x, self.base_score, self.config.learning_rate,
             max_depth=self.config.max_depth, spec=spec)
@@ -258,9 +270,11 @@ def _fit_scanned(x, y, keys, margin0, fixed_c, *, cfg: GBDTConfig,
     round-step trace) is unchanged.
     """
     def grow(margin, bins, cands):
-        g, h = grad_hess(margin, y, cfg.objective)
+        with jax.named_scope("repro.leaf_update"):
+            g, h = grad_hess(margin, y, cfg.objective)
+            gh = jnp.stack([g, h], 1)
         built = tree_lib.build_tree(
-            bins, jnp.stack([g, h], 1), cands,
+            bins, gh, cands,
             max_depth=cfg.max_depth, l2=cfg.l2,
             gamma=cfg.gamma, min_child_weight=cfg.min_child_weight,
             spec=spec, return_leaf_nodes=True,
@@ -268,7 +282,8 @@ def _fit_scanned(x, y, keys, margin0, fixed_c, *, cfg: GBDTConfig,
         t, node = built[0], built[1]
         # growth already routed every row to its leaf — gather the leaf
         # values directly instead of re-descending with predict_binned
-        margin = margin + cfg.learning_rate * t.leaf_value[node]
+        with jax.named_scope("repro.leaf_update"):
+            margin = margin + cfg.learning_rate * t.leaf_value[node]
         rep = None
         if cfg.telemetry:
             rep = round_report(margin=margin, y=y, g=g, h=h,
@@ -279,7 +294,8 @@ def _fit_scanned(x, y, keys, margin0, fixed_c, *, cfg: GBDTConfig,
     if in_scan:
         def round_step(margin, key_r):
             _bump_round_traces()
-            _, h = grad_hess(margin, y, cfg.objective)
+            with jax.named_scope("repro.leaf_update"):
+                _, h = grad_hess(margin, y, cfg.objective)
             c = proposal.propose(cfg.strategy, x, cfg.n_candidates,
                                  key=key_r, hess=h)
             bins = binning.bin_features(x, c)
@@ -293,7 +309,8 @@ def _fit_scanned(x, y, keys, margin0, fixed_c, *, cfg: GBDTConfig,
     # fixed candidate grid: host-side strategies (candidates passed in)
     # or repropose_each_round=False (proposed once from round-0 stats)
     if fixed_c is None:
-        _, h0 = grad_hess(margin0, y, cfg.objective)
+        with jax.named_scope("repro.leaf_update"):
+            _, h0 = grad_hess(margin0, y, cfg.objective)
         fixed_c = proposal.propose(cfg.strategy, x, cfg.n_candidates,
                                    key=keys[0], hess=h0)
     bins = binning.bin_features(x, fixed_c)
@@ -316,31 +333,35 @@ def fit(x: jax.Array, y: jax.Array, cfg: GBDTConfig,
       y: (n,) labels ({0,1} for logistic, real for mse).
 
     Reproduces :func:`fit_reference` tree-for-tree on the same key.
+    Under ``jax.profiler`` the call is the host span ``repro.fit``, its
+    set-up before the jitted scan ``repro.fit.prepare``.
     """
     if key is None:
         key = jax.random.PRNGKey(0)
-    x = jnp.asarray(x, jnp.float32)
-    y = jnp.asarray(y, jnp.float32)
-    t_fit0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("repro.fit"):
+        with jax.profiler.TraceAnnotation("repro.fit.prepare"):
+            x = jnp.asarray(x, jnp.float32)
+            y = jnp.asarray(y, jnp.float32)
+            t_fit0 = time.perf_counter()
+            base = _base_score(y, cfg.objective)
+            margin0 = jnp.full((x.shape[0],), base, jnp.float32)
+            keys = round_keys(key, cfg.n_trees)
+            spec = cfg.hist_spec().resolved()   # pin 'auto' outside jit
 
-    base = _base_score(y, cfg.objective)
-    margin0 = jnp.full((x.shape[0],), base, jnp.float32)
-    keys = round_keys(key, cfg.n_trees)
-    spec = cfg.hist_spec().resolved()   # pin 'auto' outside the trace
+            fixed_c = None
+            proposal_s = 0.0
+            if cfg.strategy not in proposal.TRACEABLE:
+                # host-side strategies are x-only: one proposal serves
+                # all rounds
+                t0 = time.perf_counter()
+                fixed_c = jax.block_until_ready(jnp.asarray(
+                    proposal.propose(cfg.strategy, x, cfg.n_candidates,
+                                     key=jax.random.fold_in(key, 0))))
+                proposal_s = time.perf_counter() - t0
 
-    fixed_c = None
-    proposal_s = 0.0
-    if cfg.strategy not in proposal.TRACEABLE:
-        # host-side strategies are x-only: one proposal serves all rounds
-        t0 = time.perf_counter()
-        fixed_c = jax.block_until_ready(jnp.asarray(proposal.propose(
-            cfg.strategy, x, cfg.n_candidates,
-            key=jax.random.fold_in(key, 0))))
-        proposal_s = time.perf_counter() - t0
-
-    forest, cands, margin, report = _fit_scanned(
-        x, y, keys, margin0, fixed_c, cfg=cfg, spec=spec)
-    jax.block_until_ready(margin)
+        forest, cands, margin, report = _fit_scanned(
+            x, y, keys, margin0, fixed_c, cfg=cfg, spec=spec)
+        jax.block_until_ready(margin)
     return GBDTModel(cfg, forest, base, cands,
                      proposal_seconds=proposal_s,
                      fit_seconds=time.perf_counter() - t_fit0,

@@ -72,30 +72,41 @@ def _worker_propose(cfg: boosting.GBDTConfig, key_r, x_local, hess, w_local,
     """One round's distributed proposal — traceable for every supported
     strategy, so it can live inside the scanned round step.  ``hess`` is
     already masked for pad rows; ``w_local`` is the validity weight (the
-    unweighted-quantile limit uses it so pad rows carry no rank mass)."""
+    unweighted-quantile limit uses it so pad rows carry no rank mass).
+    The local work carries the ``repro.proposal`` scope, the gathers
+    ``repro.collective``."""
     if cfg.strategy == "random":
-        gathered = lax.all_gather(local_pool, axis)              # (W, f, b)
-        return proposal.resample_gathered(key_r, gathered, cfg.n_candidates)
+        gathered = tree_lib.collective(lax.all_gather, local_pool,
+                                       axis)                    # (W, f, b)
+        with jax.named_scope("repro.proposal"):
+            return proposal.resample_gathered(key_r, gathered,
+                                              cfg.n_candidates)
     if cfg.strategy in ("weighted_quantile", "gk_quantile"):
-        local_c = proposal.weighted_quantile_candidates(
-            x_local,
-            hess if cfg.strategy == "weighted_quantile" else w_local,
-            cfg.n_candidates)
-        gathered = lax.all_gather(local_c, axis)
-        return merge_quantile_gathered(gathered, cfg.n_candidates)
+        with jax.named_scope("repro.proposal"):
+            local_c = proposal.weighted_quantile_candidates(
+                x_local,
+                hess if cfg.strategy == "weighted_quantile" else w_local,
+                cfg.n_candidates)
+        gathered = tree_lib.collective(lax.all_gather, local_c, axis)
+        with jax.named_scope("repro.proposal"):
+            return merge_quantile_gathered(gathered, cfg.n_candidates)
     if cfg.strategy == "uniform_range":
-        lo = lax.pmin(jnp.min(x_local, axis=0), axis)
-        hi = lax.pmax(jnp.max(x_local, axis=0), axis)
-        t = jnp.arange(1, cfg.n_candidates + 1) / (cfg.n_candidates + 1)
-        return lo[:, None] + (hi - lo)[:, None] * t[None, :]
+        with jax.named_scope("repro.proposal"):
+            lo, hi = jnp.min(x_local, axis=0), jnp.max(x_local, axis=0)
+        lo = tree_lib.collective(lax.pmin, lo, axis)
+        hi = tree_lib.collective(lax.pmax, hi, axis)
+        with jax.named_scope("repro.proposal"):
+            t = jnp.arange(1, cfg.n_candidates + 1) / (cfg.n_candidates + 1)
+            return lo[:, None] + (hi - lo)[:, None] * t[None, :]
     raise ValueError(f"strategy {cfg.strategy!r} has no distributed form")
 
 
 def _masked_grad_hess(margin, y_local, w_local, objective: str):
     """Per-row loss stats with pad rows zeroed: a weight-0 row contributes
     nothing to histograms, leaf values, or any psum downstream."""
-    g, h = boosting.grad_hess(margin, y_local, objective)
-    return g * w_local, h * w_local
+    with jax.named_scope("repro.leaf_update"):
+        g, h = boosting.grad_hess(margin, y_local, objective)
+        return g * w_local, h * w_local
 
 
 def _worker_base_and_pool(x_local, y_local, w_local, key, *, cfg, axis,
@@ -106,7 +117,7 @@ def _worker_base_and_pool(x_local, y_local, w_local, key, *, cfg, axis,
     from the label sum by ``w_local``, so the base score is exactly the
     unpadded one.
     """
-    ysum = lax.psum(jnp.sum(y_local * w_local), axis)
+    ysum = tree_lib.collective(lax.psum, jnp.sum(y_local * w_local), axis)
     if cfg.objective == "logistic":
         p = jnp.clip(ysum / n_global, 1e-6, 1 - 1e-6)
         base = jnp.log(p / (1 - p))
@@ -117,8 +128,9 @@ def _worker_base_and_pool(x_local, y_local, w_local, key, *, cfg, axis,
     # may be sampled — they duplicate real leading rows, so the pool
     # still only contains observed feature values.
     widx = lax.axis_index(axis)
-    local_pool = proposal.random_candidates_local(
-        jax.random.fold_in(key, widx), x_local, cfg.n_candidates)
+    with jax.named_scope("repro.proposal"):
+        local_pool = proposal.random_candidates_local(
+            jax.random.fold_in(key, widx), x_local, cfg.n_candidates)
     return base, local_pool
 
 
@@ -137,12 +149,14 @@ def _worker_fit(x_local, y_local, w_local, key, *,
         n_global=n_global)
     margin0 = jnp.full((x_local.shape[0],), base, jnp.float32)
     keys = boosting.round_keys(key, cfg.n_trees, offset=10_000)
-    psum = lambda a: lax.psum(a, axis)                        # noqa: E731
+    psum = functools.partial(tree_lib.collective, lax.psum, axis_name=axis)
 
     def grow(margin, bins, cands):
         g, h = _masked_grad_hess(margin, y_local, w_local, cfg.objective)
+        with jax.named_scope("repro.leaf_update"):
+            gh = jnp.stack([g, h], 1)
         built = tree_lib.build_tree(
-            bins, jnp.stack([g, h], 1), cands,
+            bins, gh, cands,
             max_depth=cfg.max_depth, l2=cfg.l2,
             gamma=cfg.gamma, min_child_weight=cfg.min_child_weight,
             spec=spec, axis_name=axis, return_leaf_nodes=True,
@@ -150,7 +164,8 @@ def _worker_fit(x_local, y_local, w_local, key, *,
         t, node = built[0], built[1]
         # growth already routed every local row to its leaf — gather the
         # leaf values directly instead of re-descending the tree
-        margin = margin + cfg.learning_rate * t.leaf_value[node]
+        with jax.named_scope("repro.leaf_update"):
+            margin = margin + cfg.learning_rate * t.leaf_value[node]
         rep = None
         if cfg.telemetry:
             # loss / norms psum to their global (pad-free) values, so
@@ -275,29 +290,36 @@ def fit_distributed(x, y, cfg: boosting.GBDTConfig, mesh: Mesh,
     needing no pad) are used in place; anything else goes through host
     memory.  ``reference=True`` runs the unrolled oracle loop instead of
     the scanned trainer (tests only).
+
+    Under ``jax.profiler`` the call is the host span ``repro.fit``, its
+    padding, transfer and program set-up ``repro.fit.prepare``; on the
+    device the collectives carry the ``repro.collective`` scope.
     """
     if key is None:
         key = jax.random.PRNGKey(0)
-    n_true = x.shape[0]
-    nw = mesh.shape[axis]
-    # repeat leading rows so shard shapes stay static; their weight is
-    # zero, so they never reach a psum'd statistic
-    pad = -n_true % nw
-    valid = np.concatenate([np.ones((n_true,), np.float32),
-                            np.zeros((pad,), np.float32)])
-    xs = _stage(x, NamedSharding(mesh, P(axis, None)), pad)
-    ys = _stage(y, NamedSharding(mesh, P(axis)), pad)
-    ws = jax.device_put(valid, NamedSharding(mesh, P(axis)))
+    with jax.profiler.TraceAnnotation("repro.fit"):
+        with jax.profiler.TraceAnnotation("repro.fit.prepare"):
+            n_true = x.shape[0]
+            nw = mesh.shape[axis]
+            # repeat leading rows so shard shapes stay static; their
+            # weight is zero, so they never reach a psum'd statistic
+            pad = -n_true % nw
+            valid = np.concatenate([np.ones((n_true,), np.float32),
+                                    np.zeros((pad,), np.float32)])
+            xs = _stage(x, NamedSharding(mesh, P(axis, None)), pad)
+            ys = _stage(y, NamedSharding(mesh, P(axis)), pad)
+            ws = jax.device_put(valid, NamedSharding(mesh, P(axis)))
+            program = sharded_fit(cfg, mesh, axis=axis, n_global=n_true,
+                                  reference=reference)
 
-    out = sharded_fit(cfg, mesh, axis=axis, n_global=n_true,
-                      reference=reference)(xs, ys, ws, key)
-    forest, cands, base, _margin = out[:4]
+        out = program(xs, ys, ws, key)
+        forest, cands, base, _margin = out[:4]
 
-    report = None
-    if cfg.telemetry and not reference:
-        report = out[4]
-        ag, ps = obs.collective_bytes_per_round(cfg, xs.shape[1], nw)
-        report = report._replace(all_gather_bytes=jnp.asarray(ag),
-                                 psum_bytes=jnp.asarray(ps))
-    return boosting.GBDTModel(cfg, forest, float(base), cands,
-                              report=report)
+        report = None
+        if cfg.telemetry and not reference:
+            report = out[4]
+            ag, ps = obs.collective_bytes_per_round(cfg, xs.shape[1], nw)
+            report = report._replace(all_gather_bytes=jnp.asarray(ag),
+                                     psum_bytes=jnp.asarray(ps))
+        base = float(base)                  # waits for the program
+    return boosting.GBDTModel(cfg, forest, base, cands, report=report)

@@ -133,16 +133,22 @@ def margin(forest, values, base_score, learning_rate, *,
     contraction), whereas op-by-op it reproduces the historical eager
     ``base + lr * total`` bit-for-bit.  The two O(n) elementwise
     dispatches are noise next to the traversal.
+
+    Under ``jax.profiler`` the traversal's dispatch is the host span
+    ``repro.predict.dispatch`` and the affine ``repro.predict.affine``.
     """
-    values = jnp.asarray(values,
-                         jnp.int32 if spec.binned else jnp.float32)
-    n = values.shape[0]
-    if n == 0:
-        total = jnp.zeros((0,), jnp.float32)
-    else:
-        total = _forest_sum(forest, values, jnp.zeros((n,), jnp.float32),
-                            max_depth=max_depth, spec=spec)
-    return base_score + learning_rate * total
+    with jax.profiler.TraceAnnotation("repro.predict.dispatch"):
+        values = jnp.asarray(values,
+                             jnp.int32 if spec.binned else jnp.float32)
+        n = values.shape[0]
+        if n == 0:
+            total = jnp.zeros((0,), jnp.float32)
+        else:
+            total = _forest_sum(forest, values,
+                                jnp.zeros((n,), jnp.float32),
+                                max_depth=max_depth, spec=spec)
+    with jax.profiler.TraceAnnotation("repro.predict.affine"):
+        return base_score + learning_rate * total
 
 
 def forest_predict(forest: tree_lib.Forest, values: jax.Array, *,

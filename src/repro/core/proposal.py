@@ -167,7 +167,8 @@ def propose(strategy: Strategy, x, k: int, *, key: jax.Array | None = None,
     The host-only strategies ('gk_quantile', 'exact') are x-only numpy
     and raise ``ValueError`` when ``x`` is a ``jax.core.Tracer`` (the
     trainers propose them once, outside the trace) or when
-    ``traced=True`` asks for the jit-safe path.
+    ``traced=True`` asks for the jit-safe path.  Traced, the proposal's
+    device work carries the ``repro.proposal`` scope.
 
     Args:
       x: (n, f) feature matrix.
@@ -179,25 +180,27 @@ def propose(strategy: Strategy, x, k: int, *, key: jax.Array | None = None,
     Returns:
       (f, k) sorted float32 candidates.
     """
-    if strategy == "random":
-        if key is None:
-            raise ValueError("random proposal needs a PRNG key")
-        return random_candidates(key, jnp.asarray(x), k)
-    if strategy == "weighted_quantile":
-        if hess is None:
-            hess = jnp.ones(x.shape[0], dtype=jnp.float32)
-        return weighted_quantile_candidates(jnp.asarray(x), hess, k)
-    if strategy == "uniform_range":
-        return uniform_range_candidates(jnp.asarray(x), k)
-    if strategy not in ("gk_quantile", "exact"):
-        raise ValueError(f"unknown strategy {strategy!r}")
-    if traced or isinstance(x, jax.core.Tracer):
-        raise ValueError(
-            f"strategy {strategy!r} is host-only (numpy) and cannot run "
-            f"under jit; propose outside the trace (TRACEABLE={TRACEABLE})")
-    if strategy == "gk_quantile":
-        return jnp.asarray(gk_quantile_candidates(np.asarray(x), k))
-    return jnp.asarray(exact_candidates(np.asarray(x), k))
+    with jax.named_scope("repro.proposal"):
+        if strategy == "random":
+            if key is None:
+                raise ValueError("random proposal needs a PRNG key")
+            return random_candidates(key, jnp.asarray(x), k)
+        if strategy == "weighted_quantile":
+            if hess is None:
+                hess = jnp.ones(x.shape[0], dtype=jnp.float32)
+            return weighted_quantile_candidates(jnp.asarray(x), hess, k)
+        if strategy == "uniform_range":
+            return uniform_range_candidates(jnp.asarray(x), k)
+        if strategy not in ("gk_quantile", "exact"):
+            raise ValueError(f"unknown strategy {strategy!r}")
+        if traced or isinstance(x, jax.core.Tracer):
+            raise ValueError(
+                f"strategy {strategy!r} is host-only (numpy) and cannot "
+                f"run under jit; propose outside the trace "
+                f"(TRACEABLE={TRACEABLE})")
+        if strategy == "gk_quantile":
+            return jnp.asarray(gk_quantile_candidates(np.asarray(x), k))
+        return jnp.asarray(exact_candidates(np.asarray(x), k))
 
 
 def propose_traced(strategy: Strategy, x: jax.Array, k: int,

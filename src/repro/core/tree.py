@@ -85,6 +85,14 @@ def forest_trees(forest: Forest) -> list[Tree]:
     return [Tree(*(a[i] for a in forest)) for i in range(forest.n_trees)]
 
 
+def collective(op: Callable, a: jax.Array, axis_name: str) -> jax.Array:
+    """``op(a, axis_name)`` (``lax.psum``, ``lax.all_gather``, ...) under
+    the ``repro.collective`` scope, so a profile puts the distributed
+    trainer's collectives in one layer."""
+    with jax.named_scope("repro.collective"):
+        return op(a, axis_name)
+
+
 def _level_slice(depth: int) -> slice:
     return slice(2 ** depth - 1, 2 ** (depth + 1) - 1)
 
@@ -175,7 +183,8 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
     lspec = spec.with_levels(1)        # one scan step = one level
 
     psum = (None if axis_name is None
-            else lambda a: jax.lax.psum(a, axis_name))
+            else functools.partial(collective, jax.lax.psum,
+                                   axis_name=axis_name))
     n, f = bins.shape
     n_inner = 2 ** max_depth - 1
     n_leaves = 2 ** max_depth
@@ -187,8 +196,11 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         gains, sbins = ops.split_gain(hist, l2=l2, gamma=gamma,
                                       min_child_weight=min_child_weight,
                                       backend=lspec.backend)  # (nodes, f)
-        gains = gains[:frontier]
-        sbins = sbins[:frontier]
+        with jax.named_scope("repro.route"):
+            return _route(gains[:frontier], sbins[:frontier], node, upd)
+
+    def _route(gains, sbins, node, upd):
+        """Each node's best split, then every row one level down."""
         best_f = jnp.argmax(gains, axis=1).astype(jnp.int32)  # (nodes,)
         best_gain = jnp.take_along_axis(gains, best_f[:, None], 1)[:, 0]
         best_s = jnp.take_along_axis(sbins, best_f[:, None], 1)[:, 0]
@@ -285,13 +297,15 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
     # leaf values from final-level grad/hess totals; grad/hess packed
     # into one complex64 scatter (bit-exact: lanes add independently,
     # same row order) — ~1.3x faster than the 2-wide segment_sum on CPU
-    z = jax.lax.complex(gh[:, 0].astype(jnp.float32),
-                        gh[:, 1].astype(jnp.float32))
-    seg_z = jnp.zeros((n_leaves,), jnp.complex64).at[node].add(z)
-    seg = jnp.stack([seg_z.real, seg_z.imag], -1)
+    with jax.named_scope("repro.leaf_update"):
+        z = jax.lax.complex(gh[:, 0].astype(jnp.float32),
+                            gh[:, 1].astype(jnp.float32))
+        seg_z = jnp.zeros((n_leaves,), jnp.complex64).at[node].add(z)
+        seg = jnp.stack([seg_z.real, seg_z.imag], -1)
     if psum is not None:
         seg = psum(seg)
-    leaf_value = -seg[:, 0] / (seg[:, 1] + l2)
+    with jax.named_scope("repro.leaf_update"):
+        leaf_value = -seg[:, 0] / (seg[:, 1] + l2)
     tree = Tree(feature, split_bin, threshold,
                 leaf_value.astype(jnp.float32))
     out = (tree,)

@@ -3,8 +3,8 @@
 Drives the level-synchronous inference engine
 (:mod:`repro.core.predict`) the way a serving process would: a stream
 of fixed-size microbatches through ONE warmed-up compiled traversal,
-per-request wall-clock latencies, p50/p99 + rows/s summarized as a
-:class:`repro.obs.PredictReport`.
+per-request wall-clock latencies, p50/p99 + rows/s over the timed
+loop's wall time, summarized as a :class:`repro.obs.PredictReport`.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve_gbdt \
@@ -112,13 +112,15 @@ def serve(model: boosting.GBDTModel, *, microbatch: int = 4096,
         request(batches[0]).block_until_ready()
 
     lat = np.empty((n_requests,), np.float64)
+    t_open = time.perf_counter()
     for i, xb in enumerate(batches):
         t0 = time.perf_counter()
         request(xb).block_until_ready()
         lat[i] = time.perf_counter() - t0
+    wall_s = time.perf_counter() - t_open
 
     return PredictReport(
-        latencies_s=lat, rows_per_request=microbatch,
+        latencies_s=lat, rows_per_request=microbatch, wall_s=wall_s,
         engine={
             "n_trees": cfg.n_trees, "max_depth": cfg.max_depth,
             "n_features": int(n_features),

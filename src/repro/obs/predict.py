@@ -3,9 +3,10 @@
 ``PredictReport`` is the host-side record a serving loop
 (:mod:`repro.launch.serve_gbdt`) or the predict benchmark
 (``benchmarks/bench_predict.py``) emits: per-request wall-clock
-latencies plus the workload shape, summarized into throughput and tail
-percentiles.  Follows the :mod:`repro.obs.report` JSON-schema
-convention (``repro.obs.PredictReport/v1``); consumed by
+latencies, the timed loop's wall time, and the workload shape,
+summarized into throughput and tail percentiles.  Follows the
+:mod:`repro.obs.report` JSON-schema convention
+(``repro.obs.PredictReport/v2``); consumed by
 ``repro.launch.report --section predict``.
 """
 
@@ -16,7 +17,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-SCHEMA = "repro.obs.PredictReport/v1"
+SCHEMA = "repro.obs.PredictReport/v2"
 
 
 class PredictReport(NamedTuple):
@@ -30,11 +31,16 @@ class PredictReport(NamedTuple):
         n_trees / max_depth / tree_chunk / backend / binned / n_features.
       baseline_rows_per_s: optional reference throughput (the per-tree
         scan) for the speedup field; 0 disables it.
+      wall_s: wall-clock seconds of the whole timed loop, host time
+        between requests included.  0 where the loop interleaves other
+        work (the predict benchmark's variants); throughput then falls
+        back to the sum of latencies.
     """
     latencies_s: np.ndarray
     rows_per_request: int
     engine: dict
     baseline_rows_per_s: float = 0.0
+    wall_s: float = 0.0
 
     @property
     def n_requests(self) -> int:
@@ -42,17 +48,19 @@ class PredictReport(NamedTuple):
 
     def summarize(self) -> dict:
         """Scalar summary (everything JSON-serialisable): throughput is
-        total rows over total wall-clock; percentiles are per-request."""
+        total rows over the loop's wall time (:attr:`wall_s`, else the
+        sum of latencies); percentiles are per-request."""
         lat = np.asarray(self.latencies_s, np.float64)
         if lat.size == 0:
             raise ValueError("PredictReport needs at least one request")
-        total_s = float(lat.sum())
+        total_s = float(self.wall_s) or float(lat.sum())
         rows = float(self.rows_per_request) * lat.size
         rows_per_s = rows / total_s if total_s > 0 else float("inf")
         out = {
             "n_requests": self.n_requests,
             "rows_per_request": int(self.rows_per_request),
             "rows_per_s": rows_per_s,
+            "wall_s": total_s,
             "latency_ms": {
                 "p50": float(np.percentile(lat, 50) * 1e3),
                 "p99": float(np.percentile(lat, 99) * 1e3),
