@@ -140,8 +140,7 @@ def phase_train(n_train: int, n_test: int, n_slice: int, *, rounds: int = 10,
         f"resolved backend={backend}")
     key = jax.random.PRNGKey(SEED)
     x, y = jax.device_put(xtr), jax.device_put(ytr)
-    # one fit only: at this size a round takes tens of seconds on the
-    # packed path, so the steady time is the fit less its XLA compiles
+    # one fit only: the steady time is the fit less its XLA compiles
     model, fit_s, compile_s = timed(repro.fit, x, y, cfg, key)
     steady_s = (fit_s - compile_s) / rounds
     log("train", f"smoke reading: fit {fit_s:.3f} s, of which XLA compile "

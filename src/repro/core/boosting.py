@@ -324,6 +324,11 @@ def _fit_scanned(x, y, keys, margin0, fixed_c, *, cfg: GBDTConfig,
     return tree_lib.Forest(*trees), fixed_c[None], margin, report
 
 
+def _platform(x: jax.Array) -> str:
+    """The platform a fit on ``x`` runs on: that of x's device."""
+    return next(iter(x.devices())).platform
+
+
 def fit(x: jax.Array, y: jax.Array, cfg: GBDTConfig,
         key: jax.Array | None = None) -> GBDTModel:
     """Train a GBDT model on a single host (single-compile scan trainer).
@@ -346,7 +351,8 @@ def fit(x: jax.Array, y: jax.Array, cfg: GBDTConfig,
             base = _base_score(y, cfg.objective)
             margin0 = jnp.full((x.shape[0],), base, jnp.float32)
             keys = round_keys(key, cfg.n_trees)
-            spec = cfg.hist_spec().resolved()   # pin 'auto' outside jit
+            # pin 'auto' outside jit, for the device x lives on
+            spec = cfg.hist_spec().resolved(_platform(x))
 
             fixed_c = None
             proposal_s = 0.0
@@ -382,7 +388,7 @@ def fit_reference(x: jax.Array, y: jax.Array, cfg: GBDTConfig,
 
     base = _base_score(y, cfg.objective)
     margin = jnp.full((x.shape[0],), base, jnp.float32)
-    spec = cfg.hist_spec()
+    spec = cfg.hist_spec().resolved(_platform(x))
 
     trees: list[tree_lib.Tree] = []
     cands: list[jax.Array] = []

@@ -250,8 +250,9 @@ def sharded_fit(cfg: boosting.GBDTConfig, mesh: Mesh, *, axis: str,
     """
     worker = _worker_fit_reference if reference else _worker_fit
     telemetry = cfg.telemetry and not reference
+    spec = cfg.hist_spec().resolved(mesh.devices.flat[0].platform)
     fn = functools.partial(worker, cfg=cfg, axis=axis, n_global=n_global,
-                           spec=cfg.hist_spec().resolved())
+                           spec=spec)
     return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis), P()),

@@ -148,6 +148,8 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         one of ``spec`` / ``nbins`` must be provided.
       spec: :class:`HistSpec` describing the histogram workload.  Its
         ``n_nodes`` must cover the frontier (``>= 2^(max_depth-1)``).
+        Its backend picks the histogram kernel only; split gain runs
+        ``ops.split_gain``'s own 'auto' choice.
       axis_name: if set, every histogram is lax.psum'd over this mesh
         axis (distributed-XGBoost histogram AllReduce inside shard_map);
         None = single host.
@@ -193,9 +195,10 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         """Shared tail of a level step: pick splits from the (already
         psum'd / composed) frontier panel and route rows one level down.
         ``upd`` is the level's scatter-update count (stats only)."""
+        # split gain resolves its own backend: the spec's picks only the
+        # histogram kernel
         gains, sbins = ops.split_gain(hist, l2=l2, gamma=gamma,
-                                      min_child_weight=min_child_weight,
-                                      backend=lspec.backend)  # (nodes, f)
+                                      min_child_weight=min_child_weight)
         with jax.named_scope("repro.route"):
             return _route(gains[:frontier], sbins[:frontier], node, upd)
 

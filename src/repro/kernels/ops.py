@@ -21,7 +21,7 @@ import warnings
 import jax
 
 from . import ref
-from .hist import hist_levels_left_pallas, hist_levels_pallas
+from .hist import MAX_NBINS, hist_levels_left_pallas, hist_levels_pallas
 from .split_gain import split_gain_pallas
 from .traverse import traverse_chunk_pallas
 from .flash_attention import flash_attention_pallas
@@ -30,19 +30,26 @@ from .flash_attention import flash_attention_pallas
 _BACKENDS = ("auto", "pallas", "interpret", "ref", "packed")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def default_platform() -> str:
+    """The platform computations go to unless their inputs say
+    otherwise: that of the ``jax.default_device`` in force, else JAX's
+    default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
 
 
 def resolve(backend: str) -> str:
-    """Resolve 'auto' to a concrete backend name.
+    """Resolve 'auto' to a concrete backend name for traversal and
+    split gain.
 
-    The scanned trainers call this once per fit, outside traced code, so
-    the choice is a static constant of the compiled program.  'auto'
-    picks 'packed' — the XLA path (complex64-scatter histogram, packed
-    record gathers), bit-exact vs the 'ref' oracle — on every platform,
-    TPU included: whether a Pallas kernel beats it on the chip is not
-    measured yet.  ``backend='pallas'`` still asks for the kernels.
+    'auto' picks 'packed' — the XLA path (packed record gathers for
+    traversal, the jnp split gain), bit-exact vs the 'ref' oracle — on
+    every platform, TPU included: neither the traversal nor the split-gain
+    Pallas kernel has been shown to beat it on the chip.
+    ``backend='pallas'`` still asks for the kernels.  The histogram
+    resolves on its own rule, :meth:`HistSpec.resolved`.
     """
     if backend not in _BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")
@@ -65,7 +72,8 @@ class HistSpec:
         call.  A tree builder growing ``max_depth`` levels uses
         ``n_levels = max_depth`` as its fit-wide spec and derives the
         per-call view with :meth:`with_levels`.
-      backend: 'auto' | 'pallas' | 'interpret' | 'ref' | 'packed'.
+      backend: 'auto' | 'pallas' | 'interpret' | 'ref' | 'packed';
+        'auto' resolves by platform (:meth:`resolved`).
       acc_dtype: accumulator dtype policy.  Only 'float32' is
         supported — it is the bit-exactness contract with ``hist_ref``
         — but it is part of the spec so a future bf16/f64 policy is an
@@ -106,10 +114,29 @@ class HistSpec:
                 f"acc_dtype {self.acc_dtype!r} unsupported: 'float32' is "
                 "the bit-exactness contract with hist_ref")
 
-    def resolved(self) -> "HistSpec":
+    def resolved(self, platform: str | None = None) -> "HistSpec":
         """Spec with 'auto' pinned to a concrete backend (call once per
-        fit, outside traced code)."""
-        return dataclasses.replace(self, backend=resolve(self.backend))
+        fit, outside traced code, so the choice is a static constant of
+        the compiled program).
+
+        On a TPU 'auto' picks 'pallas', the MXU one-hot contraction of
+        :mod:`repro.kernels.hist`: XLA's TPU scatter serialises the
+        'packed' path's updates (about 48.6 s of a 49.7 s round at 5M x
+        18 on one v5e).  Elsewhere it picks 'packed', the complex64
+        scatter, bit-exact vs 'ref': XLA:CPU has no MXU.  Bin counts
+        above ``MAX_NBINS``, which the kernel cannot hold exactly, stay
+        'packed'.
+
+        Args:
+          platform: the platform the fit runs on; default
+            :func:`default_platform`.
+        """
+        backend = self.backend
+        if backend == "auto":
+            platform = platform or default_platform()
+            backend = ("pallas" if platform == "tpu"
+                       and self.nbins <= MAX_NBINS else "packed")
+        return dataclasses.replace(self, backend=backend)
 
     def with_levels(self, n_levels: int) -> "HistSpec":
         """Same spec serving a different number of batched levels."""
@@ -128,9 +155,9 @@ def hist_levels(bins, node_per_level, gh, spec: HistSpec):
 
     One call accumulates the histograms of ``spec.n_levels`` node-id
     assignments of the same rows, keyed by (level, node, feature, bin):
-    the packed CPU backend issues a single complex64 scatter across all
-    levels, the Pallas backend a single launch whose grid covers the
-    whole (level, node) frontier.
+    the packed backend issues a single complex64 scatter across all
+    levels, the Pallas backend a single launch of MXU contractions whose
+    grid covers every level.
 
     Args:
       bins: (n, f) int32 bin ids in [0, spec.nbins).
@@ -148,13 +175,14 @@ def hist_levels(bins, node_per_level, gh, spec: HistSpec):
       (spec.n_levels, spec.n_nodes, f, nbins, 2) float32 — bit-exact vs
       a per-level :func:`repro.kernels.ref.hist_ref` loop on the 'ref'
       and 'packed' backends (in child mode, vs
-      :func:`repro.kernels.ref.hist_levels_left_ref`).
+      :func:`repro.kernels.ref.hist_levels_left_ref`); on 'pallas' the
+      same float32 sums added in another order.
     """
     if node_per_level.ndim != 2 or node_per_level.shape[0] != spec.n_levels:
         raise ValueError(
             f"node_per_level must be (n_levels={spec.n_levels}, n), got "
             f"shape {node_per_level.shape}")
-    backend = resolve(spec.backend)
+    backend = spec.resolved().backend
     # named_scope: the hot-loop kernels show up as one annotated region
     # per op in profiler traces (jax.profiler / perfetto), keyed by
     # backend so packed-vs-pallas time is separable
@@ -207,8 +235,8 @@ class TraverseSpec:
         candidate grid — thresholds ARE bin boundaries; NaN rows bin to
         the LAST bin (so they follow the binned routing) while raw NaN
         compares False and routes RIGHT.
-      backend: 'auto' | 'pallas' | 'interpret' | 'ref' | 'packed'; same
-        resolution rule as histograms ('auto' -> packed).
+      backend: 'auto' | 'pallas' | 'interpret' | 'ref' | 'packed';
+        'auto' -> packed on every platform (:func:`resolve`).
     """
     tree_chunk: int = 25
     binned: bool = False
@@ -300,7 +328,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     backend: str = "auto"):
     """Blockwise attention with GQA + optional sliding window."""
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else "ref"
+        backend = "pallas" if default_platform() == "tpu" else "ref"
     if backend == "ref":
         return ref.attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,

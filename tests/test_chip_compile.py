@@ -51,17 +51,43 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("f", [28, 115])
+@pytest.mark.parametrize("f", [18, 28, 115])
 @pytest.mark.parametrize("subtract", [False, True])
 def test_hist_levels_pallas_compiles(one_chip, f, subtract):
-    """A frontier of 32 nodes (depth 6) over 33 bins."""
-    n, n_nodes, nbins = 65536, 32, 33
+    """The MXU histogram over SUSY's 5,000,000 rows: a frontier of 32
+    nodes (depth 6) over 33 bins, at SUSY's 18 features and at 28 and
+    115."""
+    n, n_nodes, nbins = 5_000_000, 32, 33
     kernel = hist_levels_left_pallas if subtract else hist_levels_pallas
     panel = n_nodes // 2 if subtract else n_nodes     # parent-keyed: half
-    _compile(lambda b, nd, g: kernel(b, nd, g, n_nodes=panel, nbins=nbins),
-             _shape(one_chip, (n, f), jnp.int32),
-             _shape(one_chip, (1, n), jnp.int32),
-             _shape(one_chip, (n, 2), jnp.float32))
+    compiled = _compile(
+        lambda b, nd, g: kernel(b, nd, g, n_nodes=panel, nbins=nbins),
+        _shape(one_chip, (n, f), jnp.int32),
+        _shape(one_chip, (1, n), jnp.int32),
+        _shape(one_chip, (n, 2), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("f,n_nodes,nbins", [
+    (28, 32, 256), (115, 32, 256),      # XGBoost's default 256 bins
+    (28, 128, 33), (28, 128, 256),      # depth 8
+    (28, 512, 33), (28, 512, 256)])     # depth 10
+@pytest.mark.parametrize("subtract", [False, True])
+def test_hist_levels_pallas_compiles_wide_and_deep(one_chip, f, n_nodes,
+                                                   nbins, subtract):
+    """Frontiers whose whole output block would overrun VMEM compile
+    split into the blocks of ``hist.plan``, at 5,000,000 rows."""
+    n = 5_000_000
+    kernel = hist_levels_left_pallas if subtract else hist_levels_pallas
+    panel = n_nodes // 2 if subtract else n_nodes
+    compiled = _compile(
+        lambda b, nd, g: kernel(b, nd, g, n_nodes=panel, nbins=nbins),
+        _shape(one_chip, (n, f), jnp.int32),
+        _shape(one_chip, (1, n), jnp.int32),
+        _shape(one_chip, (n, 2), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("f", [28, 115])
@@ -98,6 +124,27 @@ def test_packed_fit_compiles_at_susy_scale(one_chip):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
+def test_pallas_fit_compiles_at_susy_scale(one_chip):
+    """The one-chip fit with the histogram on the MXU kernel, the
+    choice of 'auto' on a TPU, at 5M x 18."""
+    n, f, rounds = 5_000_000, 18, 10
+    cfg = boosting.GBDTConfig(n_trees=rounds, max_depth=6, n_candidates=32)
+    spec = cfg.hist_spec().resolved("tpu")
+    assert spec.backend == "pallas"
+    compiled = boosting._fit_scanned.lower(
+        _shape(one_chip, (n, f), jnp.float32),
+        _shape(one_chip, (n,), jnp.float32),
+        _shape(one_chip, (rounds, 2), jnp.uint32),
+        _shape(one_chip, (n,), jnp.float32),
+        None, cfg=cfg, spec=spec).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "repro.hist_levels[pallas]" in text
+    assert "repro.hist_levels[packed]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
 def test_row_sharded_fit_compiles_at_higgs_scale(topo):
     """fit_distributed's program over four chips at the published HIGGS
     size, 11M x 28: each device holds a quarter of the rows."""
@@ -113,6 +160,8 @@ def test_row_sharded_fit_compiles_at_higgs_scale(topo):
                                  sharding=NamedSharding(mesh, P())))
     compiled = distributed.sharded_fit(
         cfg, mesh, axis="data", n_global=n).lower(*args).compile()
+    # the mesh is of TPUs, so each shard runs the MXU histogram
+    assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     # x, y and the validity weight, a quarter of the rows each; the
     # chip's (8, 128) tiles pad the 28 features to 32

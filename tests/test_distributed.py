@@ -137,6 +137,12 @@ cfg_wq = boosting.GBDTConfig(n_trees=4, max_depth=4, n_candidates=8,
                              strategy="weighted_quantile")
 m_wq = distributed.fit_distributed(X, y, cfg_wq, mesh, key)
 
+# the MXU histogram kernel (through the Pallas interpreter) on every
+# shard, before the psum, grows the packed trees
+import dataclasses
+m_mxu = distributed.fit_distributed(
+    X, y, dataclasses.replace(cfg, backend="interpret"), mesh, key)
+
 out = {
     "n_devices": len(jax.devices()),
     "vs_single": forest_cmp(md.forest, ms.forest),
@@ -145,6 +151,7 @@ out = {
     "acc_dist": boosting.accuracy(md, X, y),
     "acc_single": boosting.accuracy(ms, X, y),
     "acc_wq": boosting.accuracy(m_wq, X, y),
+    "mxu_vs_packed": forest_cmp(m_mxu.forest, md.forest),
 }
 print("RESULT" + json.dumps(out))
 """
@@ -181,3 +188,9 @@ def test_padded_scan_matches_reference_worker(pad_result):
 
 def test_padded_weighted_quantile_trains(pad_result):
     assert pad_result["acc_wq"] > 0.85, pad_result
+
+
+def test_padded_pallas_histogram_matches_packed(pad_result):
+    """fit_distributed with the Pallas histogram on each shard grows
+    the same trees as with the packed scatter."""
+    assert all(pad_result["mxu_vs_packed"].values()), pad_result
