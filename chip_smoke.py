@@ -24,8 +24,9 @@ One chip, in one process:
 
 ``--chips 4`` runs ``fit_distributed`` over a four-chip ``("data",)``
 mesh on HIGGS-like data at the published HIGGS size (11,000,000 x 28),
-with and without histogram subtraction, against its ``reference=True``
-oracle and a one-chip ``fit`` on a slice, and nothing else.
+with and without histogram subtraction, twice each (the repeat builds
+no program), against its ``reference=True`` oracle and a one-chip
+``fit`` on a slice, and nothing else.
 
 Times printed here are smoke readings, not benchmarks.  A failed check
 is printed and the run goes on, so one run reports every check; the
@@ -49,9 +50,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import jax                                                      # noqa: E402
 import numpy as np                                              # noqa: E402
-from jax.sharding import Mesh                                   # noqa: E402
+from jax.sharding import Mesh, NamedSharding                    # noqa: E402
+from jax.sharding import PartitionSpec as P                     # noqa: E402
 
 import repro                                                    # noqa: E402
+from repro.core import distributed                              # noqa: E402
 from repro.data import make_dataset                             # noqa: E402
 from repro.kernels import ops, ref                              # noqa: E402
 from repro.launch import serve_gbdt                             # noqa: E402
@@ -116,6 +119,15 @@ def first_differing_tree(fa: repro.Forest, fb: repro.Forest) -> int | None:
 
 def host_forest(model: repro.GBDTModel) -> repro.Forest:
     return repro.Forest(*(np.asarray(a) for a in model.forest))
+
+
+def host_accuracy(model: repro.GBDTModel, x, y) -> float:
+    """Held-out accuracy of ``model``'s trees scored on the host CPU:
+    on a TPU the traversal of 500,000 rows compiles for over a minute
+    (PERF.md), a cost of the predict path, not of the trainer checked."""
+    with jax.default_device(jax.devices("cpu")[0]):
+        return repro.accuracy(dataclasses.replace(
+            model, forest=host_forest(model)), x, y)
 
 
 def split_agreement(fa: repro.Forest, fb: repro.Forest) -> float:
@@ -351,47 +363,55 @@ def phase_pallas(backend: str, cpu, *, n: int = 4096, f: int = 28,
 
 def phase_distributed(devices, *, n_train: int, n_test: int, n_slice: int,
                       rounds: int = 10, depth: int = 6, k: int = 32) -> dict:
-    """Row-sharded fit over ``devices``, against its oracles."""
+    """Row-sharded fit over ``devices``, against its oracles.
+
+    The training rows are laid out over the mesh once and used in place
+    by every full-size call.  Each full-size fit runs twice with a new
+    key: the repeat finds its program (no trace, no compile), so its
+    time is the steady one.  The unrolled ``reference=True`` oracle and
+    the one-device fit run on the first ``n_slice`` rows.  Accuracies
+    are scored on the host (:func:`host_accuracy`); the per-device peaks are read
+    before the one-device fit adds to the first device's."""
+    t0 = time.perf_counter()
     xtr, ytr, xte, yte, _ = make_dataset("higgs-like", n_train, n_test,
                                          seed=SEED)
     mesh = Mesh(np.array(devices), ("data",))
+    x = jax.device_put(xtr, NamedSharding(mesh, P("data", None)))
+    y = jax.device_put(ytr, NamedSharding(mesh, P("data")))
     key = jax.random.PRNGKey(SEED)
     log("distributed", f"higgs-like {n_train} x {xtr.shape[1]} over "
-        f"{len(devices)} devices, {rounds} rounds, depth {depth}, k={k}")
+        f"{len(devices)} devices, {rounds} rounds, depth {depth}, k={k}; "
+        f"data made and laid out in {time.perf_counter() - t0:.1f} s")
     base = repro.GBDTConfig(n_trees=rounds, max_depth=depth, n_candidates=k)
+    xs, ys = xtr[:n_slice], ytr[:n_slice]
     out = {}
     for subtract in (False, True):
         cfg = dataclasses.replace(base, subtract=subtract)
         tag = f"subtract={subtract}"
-        model, fit_s, compile_s = timed(repro.fit_distributed, xtr, ytr,
-                                        cfg, mesh, key)
-        steady_s = (fit_s - compile_s) / rounds
-        acc = repro.accuracy(model, xte, yte)
-        log("distributed", f"{tag}: smoke reading fit {fit_s:.3f} s, of "
-            f"which XLA compile {compile_s:.3f} s; the rest (host staging "
-            f"included) is {steady_s:.4f} s/round; held-out accuracy "
-            f"{acc:.4f}")
+        _, first_s, compile_s = timed(repro.fit_distributed, x, y, cfg,
+                                      mesh, key)
+        built = distributed.sharded_program_count()
+        model, fit_s, again_c = timed(repro.fit_distributed, x, y, cfg,
+                                      mesh, jax.random.fold_in(key, 1))
+        steady_s = fit_s / rounds
+        acc = host_accuracy(model, xte, yte)
+        log("distributed", f"{tag}: smoke reading first fit {first_s:.3f} s "
+            f"(XLA compile {compile_s:.3f} s), repeat {fit_s:.3f} s "
+            f"(compile {again_c:.3f} s): {steady_s:.4f} s/round; held-out "
+            f"accuracy {acc:.4f}; {time.perf_counter() - t0:.1f} s in")
         check(acc > 0.8, f"{tag}: held-out accuracy {acc:.4f} > 0.8")
+        check(distributed.sharded_program_count() == built,
+              f"{tag}: the repeat fit builds no program")
 
-        xs, ys = xtr[:n_slice], ytr[:n_slice]
         scan = repro.fit_distributed(xs, ys, cfg, mesh, key)
-        oracle = repro.fit_distributed(xs, ys, cfg, mesh, key, reference=True)
+        oracle = repro.fit_distributed(xs, ys, cfg, mesh, key,
+                                       reference=True)
         diff = first_differing_tree(host_forest(scan), host_forest(oracle))
         check(diff is None, f"{tag}: fit_distributed matches reference=True "
-              f"tree for tree on {n_slice} rows (first diff {diff})")
+              f"tree for tree on {n_slice} rows (first diff {diff}; "
+              f"{time.perf_counter() - t0:.1f} s in)")
         out[tag] = {"compile_s": compile_s, "steady_s_per_round": steady_s,
                     "accuracy": acc, "slice_model": scan}
-
-    with jax.default_device(devices[0]):
-        single = repro.fit(xtr[:n_slice], ytr[:n_slice], base, key)
-        acc_single = repro.accuracy(single, xte, yte)
-    acc_dist = repro.accuracy(out["subtract=False"].pop("slice_model"),
-                              xte, yte)
-    out["subtract=True"].pop("slice_model")
-    log("distributed", f"{n_slice}-row slice: held-out accuracy sharded "
-        f"{acc_dist:.4f} vs one device {acc_single:.4f}")
-    check(abs(acc_dist - acc_single) <= 0.01,
-          "sharded accuracy within 1 pp of the one-device fit")
 
     peaks = [peak_bytes(d) for d in devices]
     log("distributed", "peak_bytes_in_use per device: "
@@ -401,6 +421,18 @@ def phase_distributed(devices, *, n_train: int, n_test: int, n_slice: int,
         check(max(peaks) <= 1.25 * min(peaks),
               "per-device peak HBM about even (max <= 1.25 x min)")
     out["peak_bytes_in_use"] = peaks
+
+    with jax.default_device(devices[0]):
+        single = repro.fit(xs, ys, base, key)
+    acc_single = host_accuracy(single, xte, yte)
+    acc_dist = host_accuracy(out["subtract=False"].pop("slice_model"),
+                             xte, yte)
+    out["subtract=True"].pop("slice_model")
+    log("distributed", f"{n_slice}-row slice: held-out accuracy sharded "
+        f"{acc_dist:.4f} vs one device {acc_single:.4f}")
+    check(abs(acc_dist - acc_single) <= 0.01,
+          "sharded accuracy within 1 pp of the one-device fit")
+
     return out
 
 

@@ -91,6 +91,12 @@ class GBDTModel:
     fit_seconds: float = 0.0
     report: TrainReport | None = None   # per-round telemetry when
     #                                     config.telemetry is on
+    cover: jax.Array | None = None      # (n_trees, 2^(d+1) - 1) hessian
+    #                                     sum of every node, inner nodes
+    #                                     in heap order then the leaves
+    #                                     (XGBoost's cover); filled by
+    #                                     fit_distributed, whose checks
+    #                                     read the cross-chip sums
 
     @property
     def trees(self) -> list[tree_lib.Tree]:

@@ -25,7 +25,8 @@ unrolled loop as the semantic oracle.
 
 When ``n % n_workers != 0`` the driver pads the data with repeats of
 the leading rows so every shard is equal-sized (static shapes), and
-carries a per-row validity weight alongside: pad rows have their
+each worker weighs its rows by their validity (a row is valid when its
+global index is under the true row count): pad rows have their
 grad/hess zeroed every round and drop out of the base-score and loss
 reductions (``n_global`` is the TRUE row count), so the padded fit
 computes exactly the statistics of the unpadded data — no duplicated
@@ -109,6 +110,15 @@ def _masked_grad_hess(margin, y_local, w_local, objective: str):
         return g * w_local, h * w_local
 
 
+def _valid_rows(x_local, axis: str, n_global: int) -> jax.Array:
+    """This worker's per-row validity weight: 1 for the rows whose
+    global index lies under the true row count ``n_global``, 0 for the
+    pad rows after them."""
+    per = x_local.shape[0]
+    index = lax.axis_index(axis) * per + jnp.arange(per)
+    return (index < n_global).astype(jnp.float32)
+
+
 def _worker_base_and_pool(x_local, y_local, w_local, key, *, cfg, axis,
                           n_global):
     """Shared preamble: global base score + 'data read' candidate pool.
@@ -134,16 +144,17 @@ def _worker_base_and_pool(x_local, y_local, w_local, key, *, cfg, axis,
     return base, local_pool
 
 
-def _worker_fit(x_local, y_local, w_local, key, *,
+def _worker_fit(x_local, y_local, key, *,
                 cfg: boosting.GBDTConfig, axis: str, n_global: int,
                 spec: ops.HistSpec):
     """Traced per-worker trainer; runs identically on every 'data' slice.
 
     One lax.scan over rounds — the round step (with its all_gather /
     psum collectives) compiles once regardless of cfg.n_trees.  Returns
-    ``(forest, candidates, base, margin)`` plus a stacked
+    ``(forest, candidates, base, margin, cover)`` plus a stacked
     :class:`repro.obs.TrainReport` when ``cfg.telemetry`` is on.
     """
+    w_local = _valid_rows(x_local, axis, n_global)
     base, local_pool = _worker_base_and_pool(
         x_local, y_local, w_local, key, cfg=cfg, axis=axis,
         n_global=n_global)
@@ -160,8 +171,8 @@ def _worker_fit(x_local, y_local, w_local, key, *,
             max_depth=cfg.max_depth, l2=cfg.l2,
             gamma=cfg.gamma, min_child_weight=cfg.min_child_weight,
             spec=spec, axis_name=axis, return_leaf_nodes=True,
-            return_stats=cfg.telemetry)
-        t, node = built[0], built[1]
+            return_cover=True, return_stats=cfg.telemetry)
+        t, node, cover = built[:3]
         # growth already routed every local row to its leaf — gather the
         # leaf values directly instead of re-descending the tree
         with jax.named_scope("repro.leaf_update"):
@@ -171,10 +182,10 @@ def _worker_fit(x_local, y_local, w_local, key, *,
             # loss / norms psum to their global (pad-free) values, so
             # the report rows are replicated across workers
             rep = obs.round_report(margin=margin, y=y_local, g=g, h=h,
-                                   objective=cfg.objective, stats=built[2],
+                                   objective=cfg.objective, stats=built[3],
                                    n_global=n_global, weight=w_local,
                                    psum=psum)
-        return margin, t, rep
+        return margin, t, cover, rep
 
     if cfg.repropose_each_round:
         def round_step(margin, key_r):
@@ -184,11 +195,12 @@ def _worker_fit(x_local, y_local, w_local, key, *,
             c = _worker_propose(cfg, key_r, x_local, h, w_local,
                                 local_pool, axis)
             bins = binning.bin_features(x_local, c)
-            margin, t, rep = grow(margin, bins, c)
-            return margin, (t, c, rep)
+            margin, t, cover, rep = grow(margin, bins, c)
+            return margin, (t, c, cover, rep)
 
-        margin, (trees, cands, report) = lax.scan(round_step, margin0, keys)
-        out = (tree_lib.Forest(*trees), cands, base, margin)
+        margin, (trees, cands, cover, report) = lax.scan(round_step,
+                                                         margin0, keys)
+        out = (tree_lib.Forest(*trees), cands, base, margin, cover)
         return out + ((report,) if cfg.telemetry else ())
 
     _, h0 = _masked_grad_hess(margin0, y_local, w_local, cfg.objective)
@@ -198,25 +210,25 @@ def _worker_fit(x_local, y_local, w_local, key, *,
 
     def round_step(margin, _key_r):
         boosting._bump_round_traces()
-        margin, t, rep = grow(margin, bins0, c0)
-        return margin, (t, rep)
+        margin, t, cover, rep = grow(margin, bins0, c0)
+        return margin, (t, cover, rep)
 
-    margin, (trees, report) = lax.scan(round_step, margin0, keys)
-    out = (tree_lib.Forest(*trees), c0[None], base, margin)
+    margin, (trees, cover, report) = lax.scan(round_step, margin0, keys)
+    out = (tree_lib.Forest(*trees), c0[None], base, margin, cover)
     return out + ((report,) if cfg.telemetry else ())
 
 
-def _worker_fit_reference(x_local, y_local, w_local, key, *,
+def _worker_fit_reference(x_local, y_local, key, *,
                           cfg: boosting.GBDTConfig, axis: str,
                           n_global: int, spec: ops.HistSpec):
     """The original unrolled per-worker loop (O(n_trees) traced graph).
     Kept as the semantic oracle for the scanned worker (no telemetry)."""
+    w_local = _valid_rows(x_local, axis, n_global)
     base, local_pool = _worker_base_and_pool(
         x_local, y_local, w_local, key, cfg=cfg, axis=axis,
         n_global=n_global)
     margin = jnp.full((x_local.shape[0],), base, jnp.float32)
-    trees = []
-    cands = []
+    trees, cands, covers = [], [], []
     bins = None
 
     for r in range(cfg.n_trees):
@@ -226,28 +238,48 @@ def _worker_fit_reference(x_local, y_local, w_local, key, *,
                                 x_local, h, w_local, local_pool, axis)
             bins = binning.bin_features(x_local, c)
             cands.append(c)
-        t = tree_lib.build_tree(
+        t, cover = tree_lib.build_tree(
             bins, jnp.stack([g, h], 1), cands[-1],
             max_depth=cfg.max_depth, l2=cfg.l2,
             gamma=cfg.gamma, min_child_weight=cfg.min_child_weight,
-            spec=spec, axis_name=axis)
+            spec=spec, axis_name=axis, return_cover=True)
         trees.append(t)
+        covers.append(cover)
         margin = margin + cfg.learning_rate * tree_lib.predict_binned(
             t, bins, max_depth=cfg.max_depth)
 
     return (tree_lib.forest_from_trees(trees), jnp.stack(cands), base,
-            margin)
+            margin, jnp.stack(covers))
 
 
+# Sharded programs built so far (all meshes and configs): a repeat
+# ``fit_distributed`` call with the same config, mesh, axis and row
+# count finds its program in ``sharded_fit``'s cache and adds none.
+_programs_built = 0
+
+
+def sharded_program_count() -> int:
+    """How many row-sharded training programs have been built."""
+    return _programs_built
+
+
+@functools.lru_cache(maxsize=64)
 def sharded_fit(cfg: boosting.GBDTConfig, mesh: Mesh, *, axis: str,
                 n_global: int, reference: bool = False):
     """The jitted row-sharded training program of :func:`fit_distributed`.
 
-    Takes ``(x, y, valid, key)`` with rows sharded over ``axis`` (the
-    row count divisible by the worker count) and returns ``(forest,
-    candidates, base, margin)``, plus the stacked report when
-    ``cfg.telemetry`` is on and ``reference`` is off.
+    Takes ``(x, y, key)`` with rows sharded over ``axis`` (the row
+    count divisible by the worker count; the rows from ``n_global`` on
+    are pad) and returns ``(forest, candidates, base, margin, cover)``,
+    plus the stacked report when ``cfg.telemetry`` is on and
+    ``reference`` is off.
+
+    Cached on its arguments (the mesh fixes the platform), so a repeat
+    call returns the same jitted program and JAX's own cache then
+    serves it without tracing or compiling again.
     """
+    global _programs_built
+    _programs_built += 1
     worker = _worker_fit_reference if reference else _worker_fit
     telemetry = cfg.telemetry and not reference
     spec = cfg.hist_spec().resolved(mesh.devices.flat[0].platform)
@@ -255,8 +287,9 @@ def sharded_fit(cfg: boosting.GBDTConfig, mesh: Mesh, *, axis: str,
                            spec=spec)
     return jax.jit(jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(P(axis, None), P(axis), P(axis), P()),
-        out_specs=(P(), P(), P(), P(axis)) + ((P(),) if telemetry else ()),
+        in_specs=(P(axis, None), P(axis), P()),
+        out_specs=(P(), P(), P(), P(axis), P())
+        + ((P(),) if telemetry else ()),
         check_vma=False,
     ))
 
@@ -290,37 +323,40 @@ def fit_distributed(x, y, cfg: boosting.GBDTConfig, mesh: Mesh,
     unpadded data.  ``x`` and ``y`` already row-sharded over ``axis`` (and
     needing no pad) are used in place; anything else goes through host
     memory.  ``reference=True`` runs the unrolled oracle loop instead of
-    the scanned trainer (tests only).
+    the scanned trainer (tests only).  The model's ``cover`` holds each
+    node's hessian sum over every worker's rows.
 
-    Under ``jax.profiler`` the call is the host span ``repro.fit``, its
-    padding, transfer and program set-up ``repro.fit.prepare``; on the
-    device the collectives carry the ``repro.collective`` scope.
+    Under ``jax.profiler`` the call is the host span ``repro.fit``; its
+    set-up ``repro.fit.prepare`` holds ``repro.fit.stage`` (padding and
+    the inputs' layout) and ``repro.fit.program``
+    (finding or building the program).  On the device the collectives
+    carry the ``repro.collective`` scope.
     """
     if key is None:
         key = jax.random.PRNGKey(0)
     with jax.profiler.TraceAnnotation("repro.fit"):
         with jax.profiler.TraceAnnotation("repro.fit.prepare"):
-            n_true = x.shape[0]
-            nw = mesh.shape[axis]
-            # repeat leading rows so shard shapes stay static; their
-            # weight is zero, so they never reach a psum'd statistic
-            pad = -n_true % nw
-            valid = np.concatenate([np.ones((n_true,), np.float32),
-                                    np.zeros((pad,), np.float32)])
-            xs = _stage(x, NamedSharding(mesh, P(axis, None)), pad)
-            ys = _stage(y, NamedSharding(mesh, P(axis)), pad)
-            ws = jax.device_put(valid, NamedSharding(mesh, P(axis)))
-            program = sharded_fit(cfg, mesh, axis=axis, n_global=n_true,
-                                  reference=reference)
+            with jax.profiler.TraceAnnotation("repro.fit.stage"):
+                n_true = x.shape[0]
+                nw = mesh.shape[axis]
+                # repeat leading rows so shard shapes stay static; their
+                # weight is zero, so they never reach a psum'd statistic
+                pad = -n_true % nw
+                xs = _stage(x, NamedSharding(mesh, P(axis, None)), pad)
+                ys = _stage(y, NamedSharding(mesh, P(axis)), pad)
+            with jax.profiler.TraceAnnotation("repro.fit.program"):
+                program = sharded_fit(cfg, mesh, axis=axis,
+                                      n_global=n_true, reference=reference)
 
-        out = program(xs, ys, ws, key)
-        forest, cands, base, _margin = out[:4]
+        out = program(xs, ys, key)
+        forest, cands, base, _margin, cover = out[:5]
 
         report = None
         if cfg.telemetry and not reference:
-            report = out[4]
+            report = out[5]
             ag, ps = obs.collective_bytes_per_round(cfg, xs.shape[1], nw)
             report = report._replace(all_gather_bytes=jnp.asarray(ag),
                                      psum_bytes=jnp.asarray(ps))
         base = float(base)                  # waits for the program
-    return boosting.GBDTModel(cfg, forest, base, cands, report=report)
+    return boosting.GBDTModel(cfg, forest, base, cands, report=report,
+                              cover=cover)

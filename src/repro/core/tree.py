@@ -99,7 +99,8 @@ def _level_slice(depth: int) -> slice:
 
 @functools.partial(jax.jit, static_argnames=(
     "max_depth", "nbins", "l2", "gamma", "min_child_weight", "backend",
-    "spec", "axis_name", "return_leaf_nodes", "return_stats"))
+    "spec", "axis_name", "return_leaf_nodes", "return_cover",
+    "return_stats"))
 def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
                max_depth: int, nbins: int | None = None, l2: float = 1.0,
                gamma: float = 0.0, min_child_weight: float = 1e-6,
@@ -107,6 +108,7 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
                spec: HistSpec | None = None,
                axis_name: str | None = None,
                return_leaf_nodes: bool = False,
+               return_cover: bool = False,
                return_stats: bool = False):
     """Grow one tree on binned data.
 
@@ -157,15 +159,21 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         already routes every row to its leaf, so the scanned boosting
         trainers read the margin update as ``leaf_value[node]`` instead
         of re-descending the tree with predict_binned.
+      return_cover: also return each node's cover, the sum of the
+        hessians of the rows it holds (XGBoost's ``cover``): a
+        ``(2^(max_depth+1) - 1,)`` float32 array, the inner nodes in
+        heap order read from their level's (psum'd) histogram, then the
+        leaves from the (psum'd) leaf sums.
       return_stats: also return a :class:`TreeStats` (realized split
         count + gain summary) computed from the per-level gain panels.
         Static flag: the telemetry-off graph is unchanged.
 
     Returns:
       A :class:`Tree`, extended to ``(Tree, node)`` when
-      ``return_leaf_nodes`` is set and further to ``(..., stats)`` when
-      ``return_stats`` is set (``node`` is the (n,) int32 leaf
-      assignment, ``stats`` the :class:`TreeStats`).
+      ``return_leaf_nodes`` is set, then by ``cover`` when
+      ``return_cover`` is set and by ``stats`` when ``return_stats`` is
+      set (``node`` is the (n,) int32 leaf assignment, ``stats`` the
+      :class:`TreeStats`).
     """
     frontier = 2 ** max(max_depth - 1, 0)
     if spec is None:
@@ -200,9 +208,13 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         gains, sbins = ops.split_gain(hist, l2=l2, gamma=gamma,
                                       min_child_weight=min_child_weight)
         with jax.named_scope("repro.route"):
-            return _route(gains[:frontier], sbins[:frontier], node, upd)
+            # a node's hessians sum to the same total over any feature
+            cover = (jnp.sum(hist[:frontier, 0, :, 1], axis=-1)
+                     if return_cover else None)
+            return _route(gains[:frontier], sbins[:frontier], node, upd,
+                          cover)
 
-    def _route(gains, sbins, node, upd):
+    def _route(gains, sbins, node, upd, cover):
         """Each node's best split, then every row one level down."""
         best_f = jnp.argmax(gains, axis=1).astype(jnp.int32)  # (nodes,)
         best_gain = jnp.take_along_axis(gains, best_f[:, None], 1)[:, 0]
@@ -223,6 +235,8 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         go_left = row_bin <= lvl_sbin[node]
         node = node * 2 + jnp.where(go_left, 0, 1)
         ys = (lvl_feature, lvl_sbin, lvl_thresh)
+        if return_cover:
+            ys += (cover,)
         if return_stats:
             # unpopulated frontier tail nodes have all-zero histograms
             # and never split, so summing the full frontier is exact
@@ -278,24 +292,28 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
         else:
             node, ys = jax.lax.scan(level_step, node, None,
                                     length=max_depth)
+        feats, sbins_l, threshs = ys[:3]
+        if return_cover:
+            covers = ys[3]
         if return_stats:
-            feats, sbins_l, threshs, (ns_l, gs_l, gm_l, up_l) = ys
+            ns_l, gs_l, gm_l, up_l = ys[-1]
             stats = TreeStats(jnp.sum(ns_l).astype(jnp.int32),
                               jnp.sum(gs_l).astype(jnp.float32),
                               jnp.max(gm_l).astype(jnp.float32),
                               jnp.sum(up_l).astype(jnp.float32))
-        else:
-            feats, sbins_l, threshs = ys
 
     feature = jnp.full((n_inner,), -1, jnp.int32)
     split_bin = jnp.full((n_inner,), nbins - 1, jnp.int32)
     threshold = jnp.full((n_inner,), jnp.inf, jnp.float32)
+    inner_cover = jnp.zeros((n_inner,), jnp.float32)
     for depth in range(max_depth):
         sl = _level_slice(depth)
         w = 2 ** depth                 # populated prefix of the frontier
         feature = feature.at[sl].set(feats[depth, :w])
         split_bin = split_bin.at[sl].set(sbins_l[depth, :w])
         threshold = threshold.at[sl].set(threshs[depth, :w])
+        if return_cover:
+            inner_cover = inner_cover.at[sl].set(covers[depth, :w])
 
     # leaf values from final-level grad/hess totals; grad/hess packed
     # into one complex64 scatter (bit-exact: lanes add independently,
@@ -314,6 +332,8 @@ def build_tree(bins: jax.Array, gh: jax.Array, candidates: jax.Array, *,
     out = (tree,)
     if return_leaf_nodes:
         out += (node,)
+    if return_cover:
+        out += (jnp.concatenate([inner_cover, seg[:, 1]]),)
     if return_stats:
         out += (stats,)
     return out if len(out) > 1 else tree

@@ -155,7 +155,6 @@ def test_row_sharded_fit_compiles_at_higgs_scale(topo):
     vec = NamedSharding(mesh, P("data"))
     args = (jax.ShapeDtypeStruct((n, f), jnp.float32, sharding=rows),
             jax.ShapeDtypeStruct((n,), jnp.float32, sharding=vec),
-            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=vec),
             jax.ShapeDtypeStruct((2,), jnp.uint32,
                                  sharding=NamedSharding(mesh, P())))
     compiled = distributed.sharded_fit(
@@ -163,8 +162,8 @@ def test_row_sharded_fit_compiles_at_higgs_scale(topo):
     # the mesh is of TPUs, so each shard runs the MXU histogram
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
-    # x, y and the validity weight, a quarter of the rows each; the
-    # chip's (8, 128) tiles pad the 28 features to 32
-    quarter = (n * f * 4 + 2 * n * 4) / 4
+    # x and y, a quarter of the rows each; the chip's (8, 128) tiles pad
+    # the 28 features to 32
+    quarter = (n * f * 4 + n * 4) / 4
     assert quarter <= mem.argument_size_in_bytes <= 1.2 * quarter
     assert mem.temp_size_in_bytes < 16e9

@@ -484,7 +484,7 @@ def test_sharded_fit_resolves_by_mesh_platform(monkeypatch):
         n_global=64)
     rows = jax.ShapeDtypeStruct((64,), jnp.float32)
     text = program.lower(jax.ShapeDtypeStruct((64, 3), jnp.float32), rows,
-                         rows, jax.ShapeDtypeStruct((2,), jnp.uint32)
+                         jax.ShapeDtypeStruct((2,), jnp.uint32)
                          ).as_text(debug_info=True)
     assert "repro.hist_levels[packed]" in text
     assert "repro.hist_levels[pallas]" not in text

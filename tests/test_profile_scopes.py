@@ -1,11 +1,13 @@
 """The layers' ``jax.named_scope``s in the trainers' lowered programs,
-and the host spans of ``fit`` and ``predict`` in a profile.
+and the host spans of ``fit``, ``fit_distributed`` and ``predict`` in a
+profile.
 
 A profile reads a layer's device time from the scope in each
 operation's name stack, so every layer has to carry its scope and no
 layer's scope may enclose another's (its time would be counted twice).
 """
 
+import glob
 import re
 
 import jax
@@ -46,7 +48,7 @@ def _distributed(cfg):
     x, y = _data()
     mesh = jax.make_mesh((1,), ("data",))
     fn = distributed.sharded_fit(cfg, mesh, axis="data", n_global=256)
-    return fn, (x, y, jnp.ones((256,)), jax.random.PRNGKey(1))
+    return fn, (x, y, jax.random.PRNGKey(1))
 
 
 STRATEGIES = ["random", "weighted_quantile", "uniform_range"]
@@ -117,3 +119,42 @@ def test_scopes_leave_the_forest_unchanged():
     b = boosting.fit_reference(x, y, cfg, jax.random.PRNGKey(3))
     for u, v in zip(a.forest, b.forest):
         np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+
+def _host_spans(directory, prefix):
+    """``(start, end, name)`` of the host events named ``prefix...`` in
+    the profile written under ``directory``, by start."""
+    (path,) = glob.glob(f"{directory}/**/*.xplane.pb", recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    return sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                  for plane in profile.planes for line in plane.lines
+                  for e in line.events if e.name.startswith(prefix))
+
+
+def test_fit_distributed_stage_and_program_spans_nest_in_prepare(tmp_path):
+    x, y = _data()
+    mesh = jax.make_mesh((1,), ("data",))
+    cfg = boosting.GBDTConfig(n_trees=2, max_depth=3, n_candidates=8)
+    with jax.profiler.trace(str(tmp_path)):
+        distributed.fit_distributed(x, y, cfg, mesh)
+    spans = _host_spans(tmp_path, "repro.fit")
+    assert [s[2] for s in spans] == ["repro.fit", "repro.fit.prepare",
+                                     "repro.fit.stage", "repro.fit.program"]
+    fit, prepare, stage, program = spans
+    assert fit[0] <= prepare[0] and prepare[1] <= fit[1]
+    for child in (stage, program):
+        assert prepare[0] <= child[0] and child[1] <= prepare[1]
+    assert stage[1] <= program[0]
+
+
+def test_sharded_program_count_counts_one_per_distinct_config():
+    x, y = _data(200, 3)            # a row count no other test here uses
+    mesh = jax.make_mesh((1,), ("data",))
+    a = boosting.GBDTConfig(n_trees=1, max_depth=2, n_candidates=5)
+    b = boosting.GBDTConfig(n_trees=1, max_depth=3, n_candidates=5)
+    before = distributed.sharded_program_count()
+    built = []
+    for cfg in (a, b, a, b):
+        distributed.fit_distributed(x, y, cfg, mesh)
+        built.append(distributed.sharded_program_count() - before)
+    assert built == [1, 2, 2, 2]

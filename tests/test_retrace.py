@@ -12,6 +12,11 @@ Where the installed JAX exposes ``jax.monitoring`` event listeners, the
 same invariant is cross-checked against XLA compile events.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -85,6 +90,59 @@ def test_refit_same_config_hits_jit_cache():
     before = boosting.round_trace_count()
     boosting.fit(x, y, cfg, jax.random.PRNGKey(99))
     assert boosting.round_trace_count() - before == 0
+
+
+_SHARDED_REFIT = r"""
+import json
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core import boosting, distributed
+
+compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, duration, **_: compiles.append(event)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+mesh = Mesh(np.array(jax.devices()), ("data",))
+x = jax.random.normal(jax.random.PRNGKey(0), (2048, 5))
+y = (x[:, 0] > 0).astype(jnp.float32)
+inputs = {"host": (np.asarray(x), np.asarray(y)),
+          "sharded": (jax.device_put(x, NamedSharding(mesh, P("data", None))),
+                      jax.device_put(y, NamedSharding(mesh, P("data"))))}
+cfg = boosting.GBDTConfig(n_trees=3, max_depth=3, n_candidates=8)
+out = {"devices": len(jax.devices())}
+for name, (xs, ys) in inputs.items():
+    distributed.fit_distributed(xs, ys, cfg, mesh, jax.random.PRNGKey(1))
+    before = (boosting.round_trace_count(),
+              distributed.sharded_program_count(), len(compiles))
+    m = distributed.fit_distributed(xs, ys, cfg, mesh, jax.random.PRNGKey(2))
+    jax.block_until_ready(m.forest)
+    after = (boosting.round_trace_count(),
+             distributed.sharded_program_count(), len(compiles))
+    out[name] = [a - b for a, b in zip(after, before)]
+print("RESULT" + json.dumps(out))
+"""
+
+
+def test_sharded_refit_same_config_traces_and_builds_nothing():
+    """A second ``fit_distributed`` call with the same config, mesh and
+    row count and a new key finds its program: no round-step trace, no
+    program built, no XLA compile, for host and row-sharded inputs.
+    Four host devices, in a subprocess, as in test_distributed.py."""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src")]
+                   + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
+    proc = subprocess.run([sys.executable, "-c", _SHARDED_REFIT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT")][0]
+    out = json.loads(line[len("RESULT"):])
+    assert out["devices"] == 4
+    # (round-step traces, programs built, XLA compiles) of the repeat
+    assert out["host"] == [0, 0, 0], out
+    assert out["sharded"] == [0, 0, 0], out
 
 
 def test_traversal_traces_o1_in_n_trees():
